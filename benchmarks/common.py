@@ -4,7 +4,8 @@ and the repro.eval fleet harness.
 Scale: REPRO_FULL=1 runs the paper-scale request counts (Table I: 20k at
 ρ=1.0, 15k/25k at 0.75/1.25); the default is a 4× reduced load with the
 same operating points so `python -m benchmarks.run` finishes on one CPU.
-REPRO_WORKERS sets the sweep parallelism (default: up to 4 processes).
+REPRO_WORKERS sets the sweep parallelism (default: up to 4 processes, or
+1 when REPRO_ENGINE names a device engine).
 """
 from __future__ import annotations
 
@@ -21,6 +22,7 @@ from repro.eval import SweepSpec, run_sweep
 from repro.exp import run_experiment, save_critic
 from repro.exp.artifacts import ARTIFACTS_ENV
 from repro.sim import Simulator, make_scenario, workload_for
+from repro.sim.event_core import DEVICE_ENGINES
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 ARTIFACTS = ROOT / "artifacts"
@@ -29,8 +31,11 @@ EXPERIMENTS = ROOT / "experiments"
 # repo's store whatever the caller's cwd is
 os.environ.setdefault(ARTIFACTS_ENV, str(ARTIFACTS))
 FULL = os.environ.get("REPRO_FULL", "0") == "1"
+ENGINE = os.environ.get("REPRO_ENGINE", "numpy")
+# a device engine runs in the one process that holds the chip
 WORKERS = int(os.environ.get("REPRO_WORKERS",
-                             max(1, min(4, os.cpu_count() or 1))))
+                             1 if ENGINE in DEVICE_ENGINES
+                             else max(1, min(4, os.cpu_count() or 1))))
 
 # paper request counts (Table I / §IV-3); default = /4 for CPU runtime
 REQUESTS = {0.75: 15000, 1.0: 20000, 1.25: 25000} if FULL else \
@@ -75,9 +80,6 @@ def get_critic(retrain: bool = False) -> Critic:
 
 def critic_path() -> pathlib.Path:
     return ARTIFACTS / "critic.json"
-
-
-ENGINE = os.environ.get("REPRO_ENGINE", "numpy")
 
 
 def simulator(engine: Optional[str] = None) -> Simulator:

@@ -31,6 +31,8 @@ def main() -> None:
                          "sweep (the engine bench always profiles its own "
                          "section)")
     args = ap.parse_args()
+    from repro.jax_cache import enable_compile_cache
+    enable_compile_cache()
     t0 = time.time()
 
     from benchmarks import common

@@ -32,6 +32,8 @@ from repro.exp import (ArtifactError, ExperimentSpec, GrammarError,
                        parse_seeds, run_experiment)
 from repro.exp.provenance import completed_rows, load_prior_report
 from repro.exp.runner import expand_experiment
+from repro.jax_cache import enable_compile_cache
+from repro.sim.event_core import DEVICE_ENGINES
 
 DEFAULT_METHODS = "haf,haf-static,round-robin,lyapunov"
 DEFAULT_SCENARIOS = "paper,diurnal,flash-crowd"
@@ -71,7 +73,8 @@ def _build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--rho", type=float, default=None,
                     help="override the load point for every scenario")
     ap.add_argument("--workers", type=int, default=None,
-                    help="sweep processes [default: up to 4]")
+                    help="sweep processes [default: up to 4; 1 for the "
+                         "device engines jax and pallas]")
     ap.add_argument("--batch", type=int, default=None, metavar="B",
                     help="fan up to B seeds of each (scenario, method) cell "
                          "into one batched [B, S] simulation")
@@ -133,7 +136,9 @@ def build_experiment(args) -> ExperimentSpec:
             scenarios=parse_scenarios(DEFAULT_SCENARIOS),
             seeds=(0, 1),
             name="cli-sweep",
-            workers=max(min(4, (os.cpu_count() or 1)), 1),
+            # a device engine runs in the one process that holds the chip
+            workers=1 if args.engine in DEVICE_ENGINES
+            else max(min(4, (os.cpu_count() or 1)), 1),
             out=DEFAULT_OUT)
 
     changes = {}
@@ -218,6 +223,7 @@ def main(argv: Optional[List[str]] = None) -> int:
               f"{len(prior)} resumable, nothing run", flush=True)
         return 0
 
+    enable_compile_cache()
     t0 = time.time()
     try:
         report = run_experiment(spec, resume=not args.no_resume,
@@ -234,6 +240,12 @@ def main(argv: Optional[List[str]] = None) -> int:
     note = f", {resumed} resumed" if resumed else ""
     print(f"# report -> {spec.out}  ({time.time() - t0:.0f}s{note})",
           flush=True)
+    fallbacks = sum(1 for r in report["runs"] if r.get("batch_fallback"))
+    if report["n_failed"] or fallbacks:
+        print(f"# FAILED: {report['n_failed']} job(s) failed and {fallbacks} "
+              "row(s) came from single-replica fallback retries (see the "
+              "JOB FAILED / BATCH GROUP FAILED lines)", flush=True)
+        return 1
     return 0
 
 

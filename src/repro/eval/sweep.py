@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Sequence, Union
 
 from repro.eval.policies import make_method, normalize_method
 from repro.obs import diag
+from repro.sim.event_core import DEVICE_ENGINES
 
 ScenarioSpec = Union[str, Dict]
 
@@ -69,6 +70,18 @@ class SweepSpec:
     profile: bool = False                   # phase timers -> row profile
     metrics_interval: float = 0.0           # >0: gauge series -> timeseries
     trace_dir: Optional[str] = None         # export traces (jsonl + chrome)
+
+
+def device_workers_error(engine: str, workers: int) -> Optional[str]:
+    """Why ``workers`` processes cannot run ``engine``, or None.
+
+    A chip belongs to one process, so a device engine runs every job in
+    the process that holds it and scales with ``batch_seeds`` instead."""
+    if engine in DEVICE_ENGINES and workers > 1:
+        return (f"engine={engine!r} runs on the device, which one process "
+                f"holds: workers must be 1, not {workers}; fan seeds into "
+                "one [B, S] simulation instead (CLI: --batch B)")
+    return None
 
 
 def normalize_scenario(spec: ScenarioSpec) -> Dict:
@@ -347,14 +360,18 @@ def run_sweep(spec: SweepSpec, verbose: bool = False,
     """Execute every job, in-process or across ``spec.workers`` processes.
 
     A failing job does not abort the sweep: its slot is ``None`` (reported
-    loudly) and the surviving rows still aggregate.  Raises only when every
-    job failed.  With ``batch_seeds > 1`` jobs sharing a (scenario, method)
-    cell run as one batched simulation per chunk of seeds.
+    loudly) and the surviving rows still aggregate.  Raises when every
+    job failed, and before any job runs when a device engine is asked for
+    ``workers > 1``.  With ``batch_seeds > 1`` jobs sharing a (scenario,
+    method) cell run as one batched simulation per chunk of seeds.
 
     ``jobs`` runs an explicit (possibly filtered) job list instead of
     re-expanding the spec — the resume path of ``repro.exp`` passes the
     pending subset; rows stay aligned with the given list.
     """
+    err = device_workers_error(spec.engine, spec.workers)
+    if err:
+        raise ValueError(err)
     if jobs is None:
         jobs = expand_jobs(spec)
     elif not jobs:

@@ -38,19 +38,27 @@ ARTIFACT_PARAMS = ("critic_path",)
 
 
 def backend_info(engine: str) -> Dict:
+    """Versions, plus the device a device engine runs on.
+
+    Only a device engine initialises a JAX backend here: it runs every
+    job in this process (``workers == 1``), so the backend is the one its
+    jobs use.  A host-engine sweep may spawn workers and leaves the
+    backend alone."""
+    import jax
     import numpy as np
+    from repro.sim.event_core import DEVICE_ENGINES
     info = {
         "engine": engine,
         "python": sys.version.split()[0],
         "numpy": np.__version__,
+        "jax": jax.__version__,
         "platform": platform.platform(),
     }
-    try:
-        import jax
-        info["jax"] = jax.__version__
-        info["jax_backend"] = jax.default_backend()
-    except Exception:                        # noqa: BLE001 — jax optional here
-        info["jax"] = None
+    if engine in DEVICE_ENGINES:
+        devices = jax.devices()
+        info["device"] = {"platform": devices[0].platform,
+                          "kind": devices[0].device_kind,
+                          "count": len(devices)}
     return info
 
 

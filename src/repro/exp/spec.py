@@ -23,8 +23,9 @@ Two hashes stamp provenance and drive resume:
     equivalence suite holds bit-identical.  Extending the seed list or
     changing worker counts therefore still resumes a partial report.
 
-TOML files read through ``tomli``; writing uses a minimal emitter (the
-container has no TOML writer) restricted to the flat spec schema.
+TOML files read through the stdlib ``tomllib``; writing uses a minimal
+emitter (the stdlib has no TOML writer) restricted to the flat spec
+schema.
 """
 from __future__ import annotations
 
@@ -33,6 +34,7 @@ import hashlib
 import inspect
 import json
 import pathlib
+import tomllib
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 from repro.exp import grammar
@@ -306,10 +308,9 @@ class ExperimentSpec:
         path = pathlib.Path(path)
         text = path.read_text()
         if path.suffix.lower() == ".toml":
-            import tomli
             try:
-                data = tomli.loads(text)
-            except tomli.TOMLDecodeError as err:
+                data = tomllib.loads(text)
+            except tomllib.TOMLDecodeError as err:
                 raise SpecError(f"{path}: not valid TOML: {err}") from None
         elif path.suffix.lower() == ".json":
             data = json.loads(text)
@@ -335,6 +336,7 @@ class ExperimentSpec:
     def validate(self) -> None:
         """Raise :class:`SpecError` listing every problem (else return)."""
         from repro.eval.policies import _REGISTRY, method_names
+        from repro.eval.sweep import device_workers_error
         from repro.sim.scenarios import family_names
         from repro.sim.scenarios.registry import family_params
 
@@ -392,6 +394,9 @@ class ExperimentSpec:
             problems.append("batch must be >= 1")
         if self.workers < 1:
             problems.append("workers must be >= 1")
+        workers_err = device_workers_error(self.engine, self.workers)
+        if workers_err:
+            problems.append(workers_err)
         if self.engine == "pallas" and self.batch <= 1:
             problems.append("engine='pallas' is the batched kernel backend; "
                             "set batch > 1")

@@ -1,20 +1,17 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-"""Version compatibility shims shared by the Pallas TPU kernels.
+"""Pallas TPU kernels and their jnp/numpy references.
 
-``jax.experimental.pallas.tpu`` renamed ``TPUCompilerParams`` to
-``CompilerParams`` across JAX releases; depending on the installed
-version only one of the two names exists.  ``CompilerParams`` below
-resolves to whichever the installed JAX provides, so the kernel modules
-(`rmsnorm`, `flash_attention`, `ssd_scan`, `alloc_active_set`) work on
-both sides of the rename.
+``CompilerParams`` is re-exported from ``jax.experimental.pallas.tpu`` so
+the kernel modules share one import.  ``ROWS`` x ``LANES`` is the 32-bit
+vector tile the TPU compiler lays blocks out on: the simulator kernels
+take row blocks of ``ROWS`` rows over a lane dimension padded to a
+multiple of ``LANES``.
 """
-from jax.experimental.pallas import tpu as _pltpu
+from jax.experimental.pallas.tpu import CompilerParams
 
-try:
-    CompilerParams = _pltpu.CompilerParams          # newer JAX
-except AttributeError:
-    CompilerParams = _pltpu.TPUCompilerParams       # older JAX (≤ 0.4.x)
+ROWS = 8
+LANES = 128
 
-__all__ = ["CompilerParams"]
+__all__ = ["CompilerParams", "LANES", "ROWS"]

@@ -5,7 +5,8 @@ element-for-element in float64 — the event schedule is a chain of IEEE-754
 double divisions; float32 would desync the engines within a handful of
 events.  XLA may still fuse multiply-adds, so event times can differ from
 the scalar/numpy pair by ulps (the bit-for-bit contract binds scalar and
-numpy; this backend is held to identical discrete outcomes).  Callers must run inside :func:`jax.experimental.enable_x64` (the
+numpy; this backend is held to identical discrete outcomes).  Callers
+must run inside ``jax.enable_x64(True)`` (the
 :class:`~repro.sim.event_core.JaxEventCore` wrapper does); the flag is
 deliberately NOT flipped globally so the rest of the process keeps jax's
 default dtypes.  On CPU the per-event dispatch makes

@@ -1,8 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
-Model-facing shapes in, kernel-native shapes inside.  On CPU (this
-container) the kernels execute in ``interpret=True`` mode — the kernel body
-runs in Python for correctness validation; TPU is the performance target.
+Model-facing shapes in, kernel-native shapes inside.  Off a TPU the
+kernels execute in ``interpret=True`` mode — the kernel body runs in
+Python for correctness validation; TPU is the performance target.
 """
 from __future__ import annotations
 
@@ -11,13 +11,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import LANES, ROWS
 from repro.kernels import ref as kref
 from repro.kernels.alloc_active_set import alloc_active_set_ns
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.rmsnorm import rmsnorm_2d
 from repro.kernels.ssd_scan import ssd_scan_bhsp
-
-LANE = 128
 
 
 def _interpret() -> bool:
@@ -99,18 +98,25 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
 def alloc_active_set(psi: jax.Array, omega: jax.Array, floors: jax.Array,
                      capacity: jax.Array, mask: jax.Array
                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """[N, S] fleet allocation. Returns (alloc [N,S], feasible [N], pinned)."""
+    """[N, S] fleet allocation. Returns (alloc [N,S], feasible [N], pinned).
+
+    Pads nodes to the kernel's row block and instances to a lane multiple;
+    padded rows and lanes are masked out and stripped from the result.
+    """
     N, S = psi.shape
-    S_pad = ((S + LANE - 1) // LANE) * LANE
-    psi_p = _pad_to(psi.astype(jnp.float32), S_pad, 1)
-    omega_p = _pad_to(omega.astype(jnp.float32), S_pad, 1)
-    floors_p = _pad_to(floors.astype(jnp.float32), S_pad, 1)
-    mask_p = _pad_to(mask.astype(jnp.int32), S_pad, 1)
-    cap = capacity.astype(jnp.float32).reshape(N, 1)
+    N_pad = -(-N // ROWS) * ROWS
+    S_pad = -(-S // LANES) * LANES
+
+    def block(x, dtype):
+        return _pad_to(_pad_to(x.astype(dtype), S_pad, 1), N_pad, 0)
+
+    cap = _pad_to(capacity.astype(jnp.float32).reshape(N, 1), N_pad, 0)
     alloc, feas, pinned = alloc_active_set_ns(
-        psi_p, omega_p, floors_p, cap, mask_p, interpret=_interpret())
-    return (alloc[:, :S], feas[:, 0].astype(bool),
-            pinned[:, :S].astype(bool))
+        block(psi, jnp.float32), block(omega, jnp.float32),
+        block(floors, jnp.float32), cap, block(mask, jnp.int32),
+        interpret=_interpret())
+    return (alloc[:N, :S], feas[:N, 0].astype(bool),
+            pinned[:N, :S].astype(bool))
 
 
 # --------------------------------------------------------------------------- #

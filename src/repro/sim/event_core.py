@@ -33,8 +33,9 @@ one ``[B, S]`` :class:`~repro.sim.cluster.ClusterBlock` and the whole
 block advances per lockstep tick — ``numpy`` (elementwise-identical to
 the solo pair, so batched outcomes are bit-for-bit the solo outcomes),
 ``scalar`` (per-row reference), ``jax`` (one fused jitted device call
-per tick), and ``pallas`` (the fused step as a TPU kernel,
-:mod:`repro.kernels.event_step`, interpret-mode on CPU).
+per tick), and ``pallas`` (the fused step as a Pallas kernel,
+:mod:`repro.kernels.event_step`, in float64 interpret mode; refused on
+a TPU, where Mosaic has no float64).
 """
 from __future__ import annotations
 
@@ -220,7 +221,7 @@ class NumpyEventCore:
 class JaxEventCore:
     """jax-jitted fused step (float64) from :mod:`repro.kernels.event_core`.
 
-    Every kernel call runs inside :func:`jax.experimental.enable_x64` — the
+    Every kernel call runs inside ``jax.enable_x64(True)`` — the
     event schedule is a chain of IEEE-754 double expressions, and without
     x64 the f64 state arrays would be silently downcast to f32, desyncing
     this engine from the scalar/numpy pair within a handful of events.
@@ -234,11 +235,9 @@ class JaxEventCore:
 
     def __init__(self) -> None:
         import jax                                    # lazy: needs jax
-        from jax.experimental import enable_x64
         from repro.kernels import event_core as kec
         self._jax = jax
         self._kernel = kec
-        self._x64 = enable_x64
 
     def _put(self, *arrays):
         """Explicit host→device staging, timed as ``core.h2d`` (when
@@ -258,7 +257,7 @@ class JaxEventCore:
                         t: float) -> Tuple[float, int]:
         prof = self.profiler
         avail = cluster.head_mask & (cluster.reconfig_until <= t)
-        with self._x64():
+        with self._jax.enable_x64(True):
             rg, rc, g, c, av = self._put(
                 cluster.head_rem_g, cluster.head_rem_c,
                 cluster.alloc_g, cluster.alloc_c, avail)
@@ -283,7 +282,7 @@ class JaxEventCore:
             return
         prof = self.profiler
         act = cluster.head_mask & (cluster.reconfig_until <= t)
-        with self._x64():
+        with self._jax.enable_x64(True):
             a_rg, a_rc, g, c, av = self._put(
                 cluster.head_rem_g, cluster.head_rem_c,
                 cluster.alloc_g, cluster.alloc_c, act)
@@ -456,15 +455,12 @@ class JaxBatchedEventCore:
 
     name = "jax"
     profiler = None
-    _interpret = None            # PallasBatchedEventCore overrides
 
     def __init__(self) -> None:
         import jax                                    # lazy: needs jax
-        from jax.experimental import enable_x64
         from repro.kernels import event_core as kec
         self._jax = jax
         self._kernel = kec
-        self._x64 = enable_x64
 
     def _call(self, rg, rc, g, c, avail, t_vec, t_ev, can):
         return self._kernel.event_step_jax(rg, rc, g, c, avail,
@@ -473,7 +469,7 @@ class JaxBatchedEventCore:
     def step(self, block, t_vec, t_ev, can):
         prof = self.profiler
         avail = block.head_mask & (block.reconfig_until <= t_vec[:, None])
-        with self._x64():
+        with self._jax.enable_x64(True):
             args = (block.head_rem_g, block.head_rem_c,
                     block.alloc_g, block.alloc_c, avail, t_vec, t_ev, can)
             if prof is not None:
@@ -503,29 +499,38 @@ class JaxBatchedEventCore:
 
 
 class PallasBatchedEventCore(JaxBatchedEventCore):
-    """The [B, S] step as a Pallas kernel (one grid row per replica).
+    """The [B, S] step as a Pallas kernel (8-replica row blocks).
 
-    Compiled on TPU; everywhere else it runs in interpret mode, which
-    keeps float64 and therefore the same discrete-outcome bar as the jax
-    core.  See :mod:`repro.kernels.event_step`.
+    The block state is float64, which Mosaic cannot compile, so this
+    core runs the kernel in interpret mode, which keeps float64 and
+    therefore the same discrete-outcome bar as the jax core.  On a TPU it
+    refuses to construct rather than cast the state or interpret the
+    kernel on the chip.  See :mod:`repro.kernels.event_step`.
     """
 
     name = "pallas"
 
     def __init__(self) -> None:
         import jax
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "engine='pallas' cannot run on a TPU: its [B, S] block state "
+                "is float64 and Mosaic has no 64-bit element type; use "
+                "engine='jax' (XLA emulates float64 on the chip)")
         super().__init__()
         from repro.kernels import event_step as kes
         self._step_kernel = kes
-        self._interpret = jax.default_backend() != "tpu"
 
     def _call(self, rg, rc, g, c, avail, t_vec, t_ev, can):
         return self._step_kernel.event_step(rg, rc, g, c, avail,
                                             t_vec, t_ev, can,
-                                            interpret=self._interpret)
+                                            interpret=True)
 
 
 BATCH_ENGINES = ("numpy", "scalar", "jax", "pallas")
+# engines whose step runs on the JAX default device (the chip, on a TPU
+# host): a sweep runs their jobs in the one process that holds it
+DEVICE_ENGINES = ("jax", "pallas")
 
 
 def make_batched_event_core(engine: str):

@@ -121,6 +121,35 @@ def test_pallas_kernel_equals_oracle(seed):
         assert bool(fe[n]) == bool(f_np)
 
 
+@pytest.mark.parametrize("N,S_,seed", ((5, 12, 0), (13, 200, 1)))
+def test_pallas_kernel_padded_rows_equal_oracle(N, S_, seed):
+    """The kernel works on 8-node row blocks and 128-lane instance rows:
+    node and instance counts off those multiples are padded with masked
+    rows and lanes, which must not leak into the real rows."""
+    rng = np.random.default_rng(seed)
+    psi = rng.uniform(0, 1e14, (N, S_))
+    omega = rng.uniform(0, 100, (N, S_))
+    cap = rng.uniform(5e13, 2e14, N)
+    # floors sum to about the capacity, so feasible and infeasible rows mix
+    cap_share = (cap / (0.15 * S_))[:, None]
+    floors = np.where(rng.random((N, S_)) < 0.3,
+                      rng.uniform(0, 1, (N, S_)) * cap_share, 0.0)
+    mask = rng.random((N, S_)) < 0.9
+    al, fe, pin = kops.alloc_active_set(
+        jnp.asarray(psi), jnp.asarray(omega), jnp.asarray(floors),
+        jnp.asarray(cap), jnp.asarray(mask))
+    assert al.shape == (N, S_) and fe.shape == (N,) and pin.shape == (N, S_)
+    feas = []
+    for n in range(N):
+        a_np, f_np, _ = solve_resource_np(psi[n], omega[n], floors[n],
+                                          float(cap[n]), mask[n])
+        np.testing.assert_allclose(np.asarray(al[n]), a_np, rtol=1e-4,
+                                   atol=cap[n] * 1e-5)
+        assert bool(fe[n]) == bool(f_np)
+        feas.append(bool(f_np))
+    assert any(feas) and not all(feas)
+
+
 def test_sqrt_proportionality():
     """Unfloored instances follow g ∝ √(ωΨ) exactly (Eq. 17)."""
     psi = np.array([1e13, 4e13, 9e13, 0.0])

@@ -222,6 +222,19 @@ def test_run_batch_jax_and_pallas_cores(engine):
             [r.target_sid for r in b.requests]
 
 
+def test_pallas_core_refused_on_tpu(monkeypatch):
+    """Mosaic has no float64: on a TPU the pallas engine refuses at
+    construction and names the jax engine, instead of casting its state
+    or interpreting the kernel on the chip."""
+    jax = pytest.importorskip("jax")
+    from repro.sim.event_core import make_batched_event_core
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="float64.*engine='jax'"):
+        make_batched_event_core("pallas")
+    with pytest.raises(RuntimeError, match="float64"):
+        Simulator(paper_scenario(), engine="pallas")
+
+
 def test_run_batch_unknown_engine_rejected():
     from repro.sim.event_core import make_batched_event_core
     with pytest.raises(ValueError, match="unknown batched engine"):

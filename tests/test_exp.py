@@ -1,6 +1,7 @@
 """Declarative experiment layer: grammar, specs, artifacts, resume."""
 import dataclasses
 import json
+import os
 import pathlib
 import subprocess
 import sys
@@ -408,6 +409,77 @@ def test_cli_legacy_haf_llm_comma_error(capsys):
                   "--methods", "haf-llm:curl -s x --data a, b"])
     err = capsys.readouterr().err
     assert 'haf-llm(cmd=' in err
+
+
+# --------------------------------------------------------------------------- #
+# the device engines: one process holds the chip, and nothing fails quietly
+# --------------------------------------------------------------------------- #
+@pytest.mark.parametrize("engine", ("jax", "pallas"))
+def test_device_engine_with_workers_fails_before_any_job(engine,
+                                                         monkeypatch):
+    ran = []
+    monkeypatch.setattr(sweep_mod, "run_job", ran.append)
+    monkeypatch.setattr(sweep_mod, "run_batch_jobs",
+                        lambda jobs, **kw: ran.append(jobs))
+    spec = ExperimentSpec(methods=("haf-static",), scenarios=("paper",),
+                          seeds=(0, 1), engine=engine, batch=2, workers=2)
+    with pytest.raises(SpecError, match="workers must be 1"):
+        spec.validate()
+    with pytest.raises(ValueError, match="workers must be 1"):
+        sweep_mod.run_sweep(spec.to_sweep_spec())
+    with pytest.raises(SpecError, match="workers must be 1"):
+        run_experiment(spec)
+    assert ran == []
+
+
+def test_cli_defaults_to_one_worker_for_device_engines():
+    ap = cli._build_parser()
+    for engine in ("jax", "pallas"):
+        built = cli.build_experiment(ap.parse_args(
+            ["--engine", engine, "--batch", "4"]))
+        assert built.workers == 1
+        built.validate()
+    host = cli.build_experiment(ap.parse_args(["--engine", "numpy"]))
+    assert host.workers == max(min(4, os.cpu_count() or 1), 1)
+
+
+@pytest.mark.parametrize("fault,batch", (("none", 2), ("job", 1),
+                                         ("batch", 2)))
+def test_cli_exit_code_reports_failed_jobs(fault, batch, tmp_path,
+                                           monkeypatch):
+    real_job, real_batch = sweep_mod.run_job, sweep_mod.run_batch_jobs
+
+    def flaky_job(job):
+        if fault == "job" and job["seed"] == 1:
+            raise RuntimeError("injected job failure")
+        return real_job(job)
+
+    def flaky_batch(jobs, fallback_note=None):
+        if fault == "batch" and len(jobs) > 1:
+            raise RuntimeError("injected batch failure")
+        return real_batch(jobs, fallback_note=fallback_note)
+
+    monkeypatch.setattr(sweep_mod, "run_job", flaky_job)
+    monkeypatch.setattr(sweep_mod, "run_batch_jobs", flaky_batch)
+    out = tmp_path / "r.json"
+    rc = cli.main(["--methods", "haf-static", "--scenarios", "paper",
+                   "--seeds", "2", "--requests", "60", "--workers", "1",
+                   "--batch", str(batch), "--no-resume", "--out", str(out)])
+    report = json.loads(out.read_text())
+    fallbacks = [r for r in report["runs"] if r.get("batch_fallback")]
+    assert report["n_failed"] == (fault == "job")
+    assert len(fallbacks) == (2 if fault == "batch" else 0)
+    assert rc == (0 if fault == "none" else 1)
+
+
+def test_backend_info_names_the_device_for_device_engines():
+    from repro.exp.provenance import backend_info
+    host = backend_info("numpy")
+    assert host["jax"] == jax.__version__ and "device" not in host
+    devices = jax.devices()
+    assert backend_info("jax")["device"] == {
+        "platform": devices[0].platform, "kind": devices[0].device_kind,
+        "count": len(devices)}
 
 
 # --------------------------------------------------------------------------- #
