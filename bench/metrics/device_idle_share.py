@@ -1,0 +1,8 @@
+"""Share of the traced block's wall time in which no operation ran on the
+device, in percent."""
+
+
+def read(ctx):
+    if ctx.get("busy_s") is None or not ctx.get("window_s"):
+        return None
+    return 100.0 * (1.0 - ctx["busy_s"] / ctx["window_s"])
