@@ -305,9 +305,10 @@ def bench_profile(n_requests: int, B: int = 8,
                   engines=("numpy", "jax", "pallas")) -> Dict:
     """Per-phase wall-clock for the batched paper family on each backend.
 
-    The device engines (jax, pallas) account host↔device transfer
-    (``core.h2d`` + ``core.d2h``) separately from kernel time — the
-    ROADMAP transfer-dominance question, now measurable directly.
+    The device engines (jax, pallas) split the step's round trip into
+    ``core.h2d`` (the call: copy in and enqueue) and ``core.d2h`` (the
+    reads: wait for the device and copy back); device time itself is in
+    a device trace, not here.
     """
     from repro.obs import ObsConfig
 
@@ -339,8 +340,8 @@ def bench_profile(n_requests: int, B: int = 8,
             "events": events,
             "events_per_sec": round(events / max(prof["wall_s"], 1e-9), 1),
             "host_transfer_s": round(host, 4),
-            "kernel_s": round(phases.get("core.kernel",
-                                         {}).get("total_s", 0.0), 4),
+            "h2d_s": round(phases.get("core.h2d", {}).get("total_s", 0.0), 4),
+            "d2h_s": round(phases.get("core.d2h", {}).get("total_s", 0.0), 4),
             "phases": {k: {"total_s": round(v["total_s"], 4),
                            "count": v["count"]}
                        for k, v in sorted(phases.items())},
@@ -583,7 +584,7 @@ def main(smoke: bool = False) -> Dict:
         print(f"engine-profile,paper,engine={engine},"
               f"evps={p['events_per_sec']},"
               f"host_transfer_s={p['host_transfer_s']},"
-              f"kernel_s={p['kernel_s']}", flush=True)
+              f"h2d_s={p['h2d_s']},d2h_s={p['d2h_s']}", flush=True)
 
     pr4_cmp = bench_pr4_comparison(haf)
     if pr4_cmp.get("available"):

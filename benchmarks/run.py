@@ -69,10 +69,11 @@ def main() -> None:
                     f"tables (ran={sorted(ran)}, empty={bad})")
             dev = [e for e in ran if e in ("jax", "pallas")]
             missing = [e for e in dev
-                       if "core.kernel" not in ran[e]["phases"]]
+                       if not {"core.h2d", "core.d2h"}
+                       <= set(ran[e]["phases"])]
             if missing:
                 raise RuntimeError(
-                    "device engines missing kernel/transfer phase "
+                    "device engines missing h2d/d2h round-trip phase "
                     f"accounting: {missing}")
             # CI guard: the streamed arrival path must hold its fixed
             # O(S + window) peak-memory budget at every grid point

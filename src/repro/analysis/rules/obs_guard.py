@@ -16,7 +16,8 @@ from typing import Iterable, Optional, Set
 from repro.analysis.core import Finding, ModuleInfo, Rule, register
 
 #: hot-path modules under the contract (rel to the scan root)
-OBS_GUARD_SCOPE: Set[str] = {"sim/engine.py", "sim/cluster.py"}
+OBS_GUARD_SCOPE: Set[str] = {"sim/engine.py", "sim/cluster.py",
+                             "sim/event_core.py"}
 
 #: a call receiver is an obs hook when its final attribute (or its bare
 #: name) is one of these — self.trace.emit, observer.metrics.series,
@@ -87,8 +88,9 @@ class ObsGuard(Rule):
 
     name = "obs-guard"
     description = ("zero-overhead-when-off: trace/metrics/profiler "
-                   "calls in sim/engine.py + sim/cluster.py must sit "
-                   "inside an `if <recv> is not None` guard")
+                   "calls in sim/engine.py, sim/cluster.py and "
+                   "sim/event_core.py must sit inside an "
+                   "`if <recv> is not None` guard")
     hint = ("wrap the call: `if <receiver> is not None: <receiver>...`"
             " — obs-off runs carry None recorders and must not pay "
             "(or crash on) the hook")

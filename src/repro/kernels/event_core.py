@@ -39,11 +39,12 @@ def next_completion_jax(rem_g: jax.Array, rem_c: jax.Array,
     the argmin — such heads wait for a reallocation event.  Returns
     ``(t_next, sid)``; ``t_next`` is +inf when nothing can complete.
     """
-    dt_g = jnp.where(rem_g > 0.0, rem_g / alloc_g, 0.0)
-    dt_c = jnp.where(rem_c > 0.0, rem_c / alloc_c, 0.0)
-    cand = jnp.where(avail, t + (dt_g + dt_c), INF)
-    sid = jnp.argmin(cand)
-    return cand[sid], sid
+    with jax.named_scope("event_core.next_completion"):
+        dt_g = jnp.where(rem_g > 0.0, rem_g / alloc_g, 0.0)
+        dt_c = jnp.where(rem_c > 0.0, rem_c / alloc_c, 0.0)
+        cand = jnp.where(avail, t + (dt_g + dt_c), INF)
+        sid = jnp.argmin(cand)
+        return cand[sid], sid
 
 
 @jax.jit
@@ -58,18 +59,19 @@ def advance_jax(rem_g: jax.Array, rem_c: jax.Array,
     residuals by :class:`~repro.sim.cluster.ClusterState`, so no work
     deltas travel back).
     """
-    gpu_need = rem_g > 0.0
-    run_g = act & gpu_need & (alloc_g > 0.0)
-    stalled = act & gpu_need & (alloc_g <= 0.0)
-    tg = jnp.where(run_g, jnp.minimum(dt, rem_g / alloc_g), 0.0)
-    dg = jnp.where(run_g, alloc_g * tg, 0.0)
-    rg_new = rem_g - dg
-    rem_dt = jnp.where(run_g, dt - tg, dt)
-    cpu_ok = (act & ~stalled & (rg_new <= 0.0) & (rem_dt > 0.0)
-              & (rem_c > 0.0) & (alloc_c > 0.0))
-    tc = jnp.where(cpu_ok, jnp.minimum(rem_dt, rem_c / alloc_c), 0.0)
-    dc = jnp.where(cpu_ok, alloc_c * tc, 0.0)
-    return rg_new, rem_c - dc, run_g | cpu_ok
+    with jax.named_scope("event_core.advance"):
+        gpu_need = rem_g > 0.0
+        run_g = act & gpu_need & (alloc_g > 0.0)
+        stalled = act & gpu_need & (alloc_g <= 0.0)
+        tg = jnp.where(run_g, jnp.minimum(dt, rem_g / alloc_g), 0.0)
+        dg = jnp.where(run_g, alloc_g * tg, 0.0)
+        rg_new = rem_g - dg
+        rem_dt = jnp.where(run_g, dt - tg, dt)
+        cpu_ok = (act & ~stalled & (rg_new <= 0.0) & (rem_dt > 0.0)
+                  & (rem_c > 0.0) & (alloc_c > 0.0))
+        tc = jnp.where(cpu_ok, jnp.minimum(rem_dt, rem_c / alloc_c), 0.0)
+        dc = jnp.where(cpu_ok, alloc_c * tc, 0.0)
+        return rg_new, rem_c - dc, run_g | cpu_ok
 
 
 @jax.jit
@@ -88,24 +90,25 @@ def event_step_jax(rem_g: jax.Array, rem_c: jax.Array,
     :mod:`repro.kernels.event_step`; both evaluate the expressions of the
     numpy batched core elementwise.
     """
-    t_col = t[:, None]
-    dt_g = jnp.where(rem_g > 0.0, rem_g / alloc_g, 0.0)
-    dt_c = jnp.where(rem_c > 0.0, rem_c / alloc_c, 0.0)
-    cand = jnp.where(avail, t_col + (dt_g + dt_c), INF)
-    sid = jnp.argmin(cand, axis=1)
-    t_comp = jnp.take_along_axis(cand, sid[:, None], axis=1)[:, 0]
+    with jax.named_scope("event_core.step"):
+        t_col = t[:, None]
+        dt_g = jnp.where(rem_g > 0.0, rem_g / alloc_g, 0.0)
+        dt_c = jnp.where(rem_c > 0.0, rem_c / alloc_c, 0.0)
+        cand = jnp.where(avail, t_col + (dt_g + dt_c), INF)
+        sid = jnp.argmin(cand, axis=1)
+        t_comp = jnp.take_along_axis(cand, sid[:, None], axis=1)[:, 0]
 
-    t_next = jnp.minimum(t_comp, t_ev)
-    dt = jnp.where(live & jnp.isfinite(t_next), t_next - t, 0.0)[:, None]
-    gpu_need = rem_g > 0.0
-    run_g = avail & gpu_need & (alloc_g > 0.0) & (dt > 0.0)
-    stalled = avail & gpu_need & (alloc_g <= 0.0)
-    tg = jnp.where(run_g, jnp.minimum(dt, rem_g / alloc_g), 0.0)
-    dg = jnp.where(run_g, alloc_g * tg, 0.0)
-    rg_new = rem_g - dg
-    rem_dt = jnp.where(run_g, dt - tg, dt)
-    cpu_ok = (avail & ~stalled & (rg_new <= 0.0) & (rem_dt > 0.0)
-              & (rem_c > 0.0) & (alloc_c > 0.0))
-    tc = jnp.where(cpu_ok, jnp.minimum(rem_dt, rem_c / alloc_c), 0.0)
-    dc = jnp.where(cpu_ok, alloc_c * tc, 0.0)
-    return rg_new, rem_c - dc, run_g | cpu_ok, t_comp, sid
+        t_next = jnp.minimum(t_comp, t_ev)
+        dt = jnp.where(live & jnp.isfinite(t_next), t_next - t, 0.0)[:, None]
+        gpu_need = rem_g > 0.0
+        run_g = avail & gpu_need & (alloc_g > 0.0) & (dt > 0.0)
+        stalled = avail & gpu_need & (alloc_g <= 0.0)
+        tg = jnp.where(run_g, jnp.minimum(dt, rem_g / alloc_g), 0.0)
+        dg = jnp.where(run_g, alloc_g * tg, 0.0)
+        rg_new = rem_g - dg
+        rem_dt = jnp.where(run_g, dt - tg, dt)
+        cpu_ok = (avail & ~stalled & (rg_new <= 0.0) & (rem_dt > 0.0)
+                  & (rem_c > 0.0) & (alloc_c > 0.0))
+        tc = jnp.where(cpu_ok, jnp.minimum(rem_dt, rem_c / alloc_c), 0.0)
+        dc = jnp.where(cpu_ok, alloc_c * tc, 0.0)
+        return rg_new, rem_c - dc, run_g | cpu_ok, t_comp, sid

@@ -4,7 +4,8 @@ Three pillars, composable and individually switchable:
 
   * :mod:`repro.obs.trace`   — structured event tracing (columnar ring
     buffer; JSONL + Chrome ``trace_event`` export),
-  * :mod:`repro.obs.profile` — nested wall-clock phase timers,
+  * :mod:`repro.obs.profile` — nested wall-clock spans, counters and
+    per-tick histograms,
   * :mod:`repro.obs.metrics` — per-tick gauge time series.
 
 The engine accepts an :class:`ObsConfig` (or a prebuilt
@@ -28,8 +29,7 @@ import sys
 from typing import Callable, Optional
 
 from repro.obs.metrics import MetricsSampler
-from repro.obs.profile import (Profiler, active_profiler, format_phases,
-                               pop_profiler, push_profiler, timer)
+from repro.obs.profile import Profiler
 from repro.obs.trace import (ALLOC, ARRIVAL, CLS_LARGE_AI, CLS_NAMES,
                              CLS_RAN, CLS_SMALL_AI, COMPLETION, DEGRADED,
                              DEGRADED_NAMES, DROP, EPOCH, KIND_NAMES,
@@ -39,8 +39,7 @@ from repro.obs.trace import (ALLOC, ARRIVAL, CLS_LARGE_AI, CLS_NAMES,
 __all__ = [
     "ObsConfig", "RunObserver", "make_observer",
     "TraceRecorder", "Profiler", "MetricsSampler",
-    "timer", "active_profiler", "push_profiler", "pop_profiler",
-    "format_phases", "load_jsonl", "diag", "set_diag_sink",
+    "load_jsonl", "diag", "set_diag_sink",
     "ARRIVAL", "COMPLETION", "DROP", "MIGRATION", "EPOCH", "ALLOC",
     "NODE_DOWN", "NODE_UP", "DEGRADED", "DEGRADED_NAMES", "degraded_code",
     "KIND_NAMES", "CLS_LARGE_AI", "CLS_SMALL_AI", "CLS_RAN", "CLS_NAMES",

@@ -51,7 +51,8 @@ import numpy as np
 from repro import obs as _obs
 from repro.sim.cluster import (ClusterBlock, ClusterState, Job,
                                deadline_allocate_block)
-from repro.sim.event_core import make_batched_event_core, make_event_core
+from repro.sim.event_core import (DEVICE_ENGINES, device_annotator,
+                                  make_batched_event_core, make_event_core)
 from repro.sim.snapshot import EpochSnapshot
 from repro.sim.stream import as_arrival_stream
 from repro.sim.types import (InstanceCategory, MigrationAction, Request,
@@ -827,8 +828,6 @@ class _Replica:
                         degraded=dict(self.degraded) if self.degraded
                         else None)
         if observer is not None:
-            if observer.profiler is not None:
-                res.profile = observer.profiler.report()
             if observer.metrics is not None:
                 res.timeseries = observer.metrics.series(self.b)
             res.trace = observer.trace
@@ -925,6 +924,15 @@ class Simulator:
                 "use run_batch, or engine='numpy' for single traces")
         observer = _obs.make_observer(obs if obs is not None else self.obs,
                                       B=1, engine=self.engine)
+        prof = metrics = None
+        if observer is not None:
+            prof = observer.profiler
+            metrics = observer.metrics
+        if prof is not None:
+            if self.engine in DEVICE_ENGINES:
+                prof.annotate = device_annotator()
+            depth = prof.depth
+            prof.begin("engine.build")
         rep = _Replica(self.scenario, self.epoch_interval, self.drop_expired,
                        requests, placement, allocation, rr_dispatch,
                        epoch_hook, retain_requests=retain_requests)
@@ -934,15 +942,13 @@ class Simulator:
         core = make_event_core(self.engine)
         cluster = rep.cluster
         heap = rep.heap
-        prof = metrics = None
         if observer is not None:
             rep.trace = observer.trace
-            rep.metrics = metrics = observer.metrics
+            rep.metrics = metrics
             cluster.trace = observer.trace
-            prof = observer.profiler
             core.profiler = prof
-            if prof is not None:
-                _obs.push_profiler(prof)
+        if prof is not None:
+            prof.end()
         wall_t0 = perf_counter()
 
         # single loop over timed events AND queue completions: it must keep
@@ -951,10 +957,16 @@ class Simulator:
         # outage/reconfiguration ends)
         try:
             while True:
-                if not rep.stream_done:
-                    rep.refill()    # windowed heap refill (no-op once drained)
                 if prof is not None:
-                    _t0 = perf_counter()
+                    prof.begin("engine.tick", rep.n_events)
+                if not rep.stream_done:
+                    if prof is not None:
+                        prof.begin("engine.refill")
+                    rep.refill()    # windowed heap refill (no-op once drained)
+                    if prof is not None:
+                        prof.end()
+                if prof is not None:
+                    prof.begin("engine.step")
                 t_comp, sid_comp = core.next_completion(cluster, rep.t)
                 t_ev = heap[0][0] if heap else INF
                 t_next = min(t_comp, t_ev)
@@ -967,8 +979,8 @@ class Simulator:
                 rep.t = t_next
                 rep.n_events += 1
                 if prof is not None:
-                    prof.add("engine.step", perf_counter() - _t0)
-                    _t0 = perf_counter()
+                    prof.end()
+                    prof.begin("engine.events")
 
                 if t_comp <= t_ev:
                     rep.mark(sid_comp)
@@ -978,39 +990,45 @@ class Simulator:
                     rep.handle_timed()
                     pending = rep.pending_epoch is not None
                 if prof is not None:
-                    prof.add("engine.events", perf_counter() - _t0)
+                    prof.end()
                 if pending:
                     if prof is not None:
-                        _t0 = perf_counter()
+                        prof.begin("epoch.decide")
                     dispatch_epoch_decisions((rep,))
                     if prof is not None:
-                        prof.add("epoch.decide", perf_counter() - _t0)
+                        prof.end()
 
                 rep.cleanup_drops()
                 nodes = rep.realloc_nodes()
                 if nodes is None or nodes:
                     if prof is not None:
-                        _t0 = perf_counter()
+                        prof.begin("allocator.solve")
                     if nodes is None:
                         allocation.allocate(cluster, rep.t)
                     else:
                         allocation.allocate(cluster, rep.t, nodes)
                     if prof is not None:
-                        prof.add("allocator.solve", perf_counter() - _t0)
+                        prof.end()
                 if metrics is not None:
                     metrics.maybe_sample(0, rep.t, cluster)
+                if prof is not None:
+                    prof.end()
         finally:
+            # the last tick breaks out with its spans open
             if prof is not None:
-                _obs.pop_profiler(prof)
-            core.profiler = None
+                prof.close_open(depth)
 
         wall = perf_counter() - wall_t0
         if prof is not None:
             prof.add("run", wall)
+            prof.begin("engine.collect")
         if metrics is not None:
             metrics.finalize(0, rep.t, cluster)
-        return rep.result(wall_s=wall, engine=self.engine,
-                          observer=observer)
+        res = rep.result(wall_s=wall, engine=self.engine, observer=observer)
+        if prof is not None:
+            prof.end()
+            res.profile = prof.report()
+        return res
 
     # ------------------------------------------------------------------ #
     def run_batch(self, workloads: Sequence[List[Request]],
@@ -1040,6 +1058,18 @@ class Simulator:
         simulator's engine name.
         """
         B = len(workloads)
+        engine_name = engine or self.engine
+        observer = _obs.make_observer(obs if obs is not None else self.obs,
+                                      B=B, engine=engine_name)
+        prof = metrics = None
+        if observer is not None:
+            prof = observer.profiler
+            metrics = observer.metrics
+        if prof is not None:
+            if engine_name in DEVICE_ENGINES:
+                prof.annotate = device_annotator()
+            depth = prof.depth
+            prof.begin("engine.build")
         placements = _realize_policies(placements, B, "placement")
         allocations = _realize_policies(allocations, B, "allocation")
         if epoch_hooks is not None and len(epoch_hooks) != B:
@@ -1053,14 +1083,8 @@ class Simulator:
                          retain_requests=retain_requests)
                 for b in range(B)]
         block = ClusterBlock([rep.cluster for rep in reps])
-        engine_name = engine or self.engine
         core = make_batched_event_core(engine_name)
-        observer = _obs.make_observer(obs if obs is not None else self.obs,
-                                      B=B, engine=engine_name)
-        prof = metrics = None
         if observer is not None:
-            prof = observer.profiler
-            metrics = observer.metrics
             core.profiler = prof
             for b, rep in enumerate(reps):
                 rep.trace = observer.trace
@@ -1068,8 +1092,6 @@ class Simulator:
                 rep.b = b
                 rep.cluster.trace = observer.trace
                 rep.cluster.trace_b = b
-            if prof is not None:
-                _obs.push_profiler(prof)
         # the cross-replica allocation gather is exact only for the
         # paper's allocator; other policies re-solve per replica (the
         # same code path a solo run uses)
@@ -1097,6 +1119,7 @@ class Simulator:
                 node_lists[b] = nodes          # None = full re-solve
                 state["any_alloc"] = True
             else:
+                # per replica-event: timed, but no span of its own
                 if prof is not None:
                     _t0 = perf_counter()
                 if nodes is None:
@@ -1107,11 +1130,16 @@ class Simulator:
                     prof.add("allocator.solve", perf_counter() - _t0)
             t_ev[b] = rep.heap[0][0] if rep.heap else INF
 
+        if prof is not None:
+            prof.end()
+        tick = 0
         wall_t0 = perf_counter()
         try:
             while n_live:
                 if prof is not None:
-                    _ts = perf_counter()
+                    prof.begin("engine.tick", tick)
+                    tick += 1
+                    prof.begin("engine.step")
                 for b, rep in enumerate(reps):
                     # per-replica stream cursor: pull the next window(s)
                     # before the fused compute+advance step reads t_ev —
@@ -1119,7 +1147,11 @@ class Simulator:
                     # arrival can precede it (host-scalar check only)
                     if not rep.stream_done and not rep.done \
                             and t_ev[b] >= rep.loaded_until:
+                        if prof is not None:
+                            prof.begin("engine.refill")
                         rep.refill()
+                        if prof is not None:
+                            prof.end()
                         t_ev[b] = rep.heap[0][0] if rep.heap else INF
                     can_step[b] = not rep.done and rep.n_events < max_events
                 t_comp, sids = core.step(block, t_vec, t_ev, can_step)
@@ -1127,8 +1159,8 @@ class Simulator:
                 finite = np.isfinite(t_next)
                 np.copyto(t_vec, t_next, where=can_step & finite)
                 if prof is not None:
-                    prof.add("engine.step", perf_counter() - _ts)
-                    _ts = perf_counter()
+                    prof.end()
+                    prof.begin("engine.events")
 
                 state["any_alloc"] = False
                 at_epoch: List[int] = []
@@ -1158,38 +1190,46 @@ class Simulator:
                             continue
                     settle(b, rep)
                 if prof is not None:
-                    prof.add("engine.events", perf_counter() - _ts)
+                    prof.end()
 
                 if at_epoch:
                     # one batched decide for every replica at an epoch
                     # boundary this tick, then their deferred settle
                     if prof is not None:
-                        _ts = perf_counter()
+                        prof.begin("epoch.decide")
                     dispatch_epoch_decisions([reps[b] for b in at_epoch])
                     for b in at_epoch:
                         settle(b, reps[b])
                     if prof is not None:
-                        prof.add("epoch.decide", perf_counter() - _ts)
+                        prof.end()
                 if state["any_alloc"]:
                     if prof is not None:
-                        _ts = perf_counter()
+                        prof.begin("allocator.solve")
                     deadline_allocate_block(block, t_vec, node_lists)
                     if prof is not None:
-                        prof.add("allocator.solve", perf_counter() - _ts)
+                        prof.end()
                 if metrics is not None:
                     for b, rep in enumerate(reps):
                         if not rep.done:
                             metrics.maybe_sample(b, rep.t, rep.cluster)
+                if prof is not None:
+                    prof.end()
         finally:
             if prof is not None:
-                _obs.pop_profiler(prof)
-            core.profiler = None
+                prof.close_open(depth)
 
         wall = perf_counter() - wall_t0
         if prof is not None:
             prof.add("run", wall)
+            prof.begin("engine.collect")
         if metrics is not None:
             for b, rep in enumerate(reps):
                 metrics.finalize(b, rep.t, rep.cluster)
-        return [rep.result(wall_s=wall, engine=engine_name,
-                           observer=observer) for rep in reps]
+        results = [rep.result(wall_s=wall, engine=engine_name,
+                              observer=observer) for rep in reps]
+        if prof is not None:
+            prof.end()
+            report = prof.report()
+            for res in results:
+                res.profile = report
+        return results

@@ -39,7 +39,6 @@ a TPU, where Mosaic has no float64).
 """
 from __future__ import annotations
 
-from time import perf_counter
 from typing import Optional, Tuple
 
 import numpy as np
@@ -50,9 +49,30 @@ INF = float("inf")
 
 # Every core exposes ``profiler`` (a repro.obs Profiler or None, attached
 # by the Simulator per run).  The numpy/scalar cores' work is already
-# timed by the driver's "engine.step" phase; the jax/pallas cores use it
-# to split the step into core.h2d / core.kernel / core.d2h — the
-# host↔device transfer accounting ROADMAP item 1 asks for.
+# timed by the simulator's "engine.step" span; the jax/pallas cores split it
+# into two spans around the calls the unprofiled path makes anyway:
+# ``core.h2d`` — the call into the jitted program, which copies the host
+# arrays and enqueues the step — and ``core.d2h`` — the reads of its
+# outputs, which wait for the device and copy back.  They count
+# ``core.ticks`` (steps dispatched) and ``core.h2d_bytes`` /
+# ``core.d2h_bytes`` (the arrays passed and read back).  Profiling adds
+# no staging and no synchronisation: device time is the device trace's.
+
+
+def device_annotator():
+    """The :attr:`repro.obs.Profiler.annotate` hook of the device
+    engines: while a ``jax.profiler`` trace is being recorded, each span
+    opens a host ``TraceAnnotation`` (the tick a ``StepTraceAnnotation``
+    with its index as ``step_num``) on the profiler's clock."""
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+    def annotate(name: str, step: Optional[int]):
+        if not TraceAnnotation.is_enabled():
+            return None
+        if step is None:
+            return TraceAnnotation(name)
+        return StepTraceAnnotation(name, step_num=step)
+    return annotate
 
 
 class ScalarEventCore:
@@ -239,40 +259,29 @@ class JaxEventCore:
         self._jax = jax
         self._kernel = kec
 
-    def _put(self, *arrays):
-        """Explicit host→device staging, timed as ``core.h2d`` (when
-        profiling is off the kernel call transfers implicitly and the
-        split is not observable)."""
-        prof = self.profiler
-        if prof is None:
-            return arrays
-        t0 = perf_counter()
-        out = tuple(self._jax.device_put(a) for a in arrays)
-        for o in out:
-            o.block_until_ready()
-        prof.add("core.h2d", perf_counter() - t0)
-        return out
-
     def next_completion(self, cluster: ClusterState,
                         t: float) -> Tuple[float, int]:
         prof = self.profiler
         avail = cluster.head_mask & (cluster.reconfig_until <= t)
-        with self._jax.enable_x64(True):
-            rg, rc, g, c, av = self._put(
-                cluster.head_rem_g, cluster.head_rem_c,
+        args = (cluster.head_rem_g, cluster.head_rem_c,
                 cluster.alloc_g, cluster.alloc_c, avail)
+        with self._jax.enable_x64(True):
             if prof is not None:
-                t0 = perf_counter()
-            best, sid = self._kernel.next_completion_jax(rg, rc, g, c,
-                                                         av, t)
+                prof.begin("core.h2d")
+            d_best, d_sid = self._kernel.next_completion_jax(*args, t)
             if prof is not None:
-                best.block_until_ready()
-                prof.add("core.kernel", perf_counter() - t0)
-                t0 = perf_counter()
-            best = float(best)
-            sid = int(sid)
+                prof.end()
+                prof.add_count("core.ticks", 1)
+                # the arrays and the float64 time ``t``
+                prof.add_count("core.h2d_bytes",
+                               sum(a.nbytes for a in args) + 8)
+                prof.begin("core.d2h")
+            best = float(d_best)
+            sid = int(d_sid)
             if prof is not None:
-                prof.add("core.d2h", perf_counter() - t0)
+                prof.end()
+                prof.add_count("core.d2h_bytes",
+                               d_best.nbytes + d_sid.nbytes)
         if not np.isfinite(best):
             return INF, -1
         return best, sid
@@ -282,23 +291,25 @@ class JaxEventCore:
             return
         prof = self.profiler
         act = cluster.head_mask & (cluster.reconfig_until <= t)
-        with self._jax.enable_x64(True):
-            a_rg, a_rc, g, c, av = self._put(
-                cluster.head_rem_g, cluster.head_rem_c,
+        args = (cluster.head_rem_g, cluster.head_rem_c,
                 cluster.alloc_g, cluster.alloc_c, act)
+        with self._jax.enable_x64(True):
             if prof is not None:
-                t0 = perf_counter()
-            rg, rc, started = self._kernel.advance_jax(a_rg, a_rc, g, c,
-                                                       av, dt)
+                prof.begin("core.h2d")
+            rg, rc, started = self._kernel.advance_jax(*args, dt)
             if prof is not None:
-                rg.block_until_ready()
-                prof.add("core.kernel", perf_counter() - t0)
-                t0 = perf_counter()
+                prof.end()
+                # the arrays and the float64 step ``dt``
+                prof.add_count("core.h2d_bytes",
+                               sum(a.nbytes for a in args) + 8)
+                prof.begin("core.d2h")
             cluster.head_rem_g[:] = rg
             cluster.head_rem_c[:] = rc
             cluster.head_started |= np.asarray(started)
             if prof is not None:
-                prof.add("core.d2h", perf_counter() - t0)
+                prof.end()
+                prof.add_count("core.d2h_bytes",
+                               rg.nbytes + rc.nbytes + started.nbytes)
 
 
 ENGINES = ("numpy", "scalar", "jax")
@@ -469,32 +480,27 @@ class JaxBatchedEventCore:
     def step(self, block, t_vec, t_ev, can):
         prof = self.profiler
         avail = block.head_mask & (block.reconfig_until <= t_vec[:, None])
+        args = (block.head_rem_g, block.head_rem_c,
+                block.alloc_g, block.alloc_c, avail, t_vec, t_ev, can)
         with self._jax.enable_x64(True):
-            args = (block.head_rem_g, block.head_rem_c,
-                    block.alloc_g, block.alloc_c, avail, t_vec, t_ev, can)
             if prof is not None:
-                # explicit staging splits the tick into h2d / kernel / d2h
-                # — the per-phase numbers ROADMAP item 1 needs to pin the
-                # host↔device round-trip cost of this backend
-                t0 = perf_counter()
-                args = tuple(self._jax.device_put(a) for a in args)
-                for a in args:
-                    a.block_until_ready()
-                prof.add("core.h2d", perf_counter() - t0)
-                t0 = perf_counter()
+                prof.begin("core.h2d")
             out = self._call(*args)
             if prof is not None:
-                for o in out:
-                    o.block_until_ready()
-                prof.add("core.kernel", perf_counter() - t0)
-                t0 = perf_counter()
+                prof.end()
+                prof.add_count("core.ticks", 1)
+                prof.add_count("core.h2d_bytes",
+                               sum(a.nbytes for a in args))
+                prof.begin("core.d2h")
             rg, rc, started, t_comp, sid = out
             block.head_rem_g[...] = np.asarray(rg)
             block.head_rem_c[...] = np.asarray(rc)
             block.head_started |= np.asarray(started)
             ret = np.asarray(t_comp), np.asarray(sid, np.int64)
             if prof is not None:
-                prof.add("core.d2h", perf_counter() - t0)
+                prof.end()
+                prof.add_count("core.d2h_bytes",
+                               sum(o.nbytes for o in out))
             return ret
 
 

@@ -292,13 +292,186 @@ def test_profile_phases_numpy():
     assert res.profile["wall_s"] > 0
 
 
-def test_profile_separates_host_transfer_on_jax():
-    pytest.importorskip("jax")
-    results = _batched("paper", engine="jax", n=100, B=2,
-                       obs=ObsConfig(profile=True))
-    phases = results[0].profile["phases"]
-    for name in ("core.h2d", "core.kernel", "core.d2h"):
+def test_profile_separates_host_transfer_on_jax(monkeypatch):
+    jax = pytest.importorskip("jax")
+    puts = []
+    real_put = jax.device_put
+
+    def counting_put(*args, **kwargs):
+        puts.append(1)
+        return real_put(*args, **kwargs)
+
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    off = _batched("paper", engine="jax", n=100, B=2)
+    n_off = len(puts)
+    on = _batched("paper", engine="jax", n=100, B=2,
+                  obs=ObsConfig(profile=True))
+    # profiling stages nothing the unprofiled path does not
+    assert len(puts) - n_off == n_off
+    assert [_fingerprint(r) for r in on] == [_fingerprint(r) for r in off]
+    phases = on[0].profile["phases"]
+    for name in ("core.h2d", "core.d2h"):
         assert name in phases, f"jax profile missing {name}"
+        assert phases[name]["parent"] == "engine.step"
+    assert "core.kernel" not in phases
+
+
+# --------------------------------------------------------------------------- #
+# Profiler: spans, self time, the annotation hook, tick percentiles, counters
+# --------------------------------------------------------------------------- #
+class _Clock:
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+def test_span_nesting_and_self_time(monkeypatch):
+    from repro.obs import profile
+    clock = _Clock()
+    monkeypatch.setattr(profile, "perf_counter", clock)
+    prof = profile.Profiler()
+    prof.begin("engine.tick", 0)
+    prof.begin("engine.step")
+    clock.t = 1.0
+    prof.begin("core.h2d")
+    clock.t = 3.0
+    prof.end()
+    prof.add("allocator.solve", 0.5)        # a closed child, timed outside
+    clock.t = 4.0
+    prof.end()
+    prof.begin("engine.events")
+    clock.t = 10.0
+    prof.end()
+    prof.end()
+    ph = prof.report()["phases"]
+    assert ph["engine.tick"]["total_s"] == 10.0
+    assert ph["engine.tick"]["self_s"] == 0.0
+    assert ph["engine.step"]["total_s"] == 4.0
+    assert ph["engine.step"]["self_s"] == pytest.approx(4.0 - 2.0 - 0.5)
+    assert ph["core.h2d"]["self_s"] == 2.0
+    assert ph["engine.events"]["total_s"] == 6.0
+    assert {n: p["parent"] for n, p in ph.items()} == {
+        "engine.tick": None, "engine.step": "engine.tick",
+        "core.h2d": "engine.step", "allocator.solve": "engine.step",
+        "engine.events": "engine.tick"}
+    assert prof.report()["wall_s"] == 10.0      # no "run": the root spans
+    prof.begin("engine.tick", 1)
+    prof.begin("engine.step")
+    prof.close_open(0)
+    assert prof.depth == 0
+    assert ph["engine.tick"]["count"] == 1
+    assert prof.report()["phases"]["engine.tick"]["count"] == 2
+
+
+def test_annotation_hook_is_called_only_while_installed():
+    from repro.obs import Profiler
+    calls = []
+
+    class Ann:
+        def __init__(self, name, step):
+            self.name, self.step = name, step
+
+        def __enter__(self):
+            calls.append(("enter", self.name, self.step))
+
+        def __exit__(self, *exc):
+            calls.append(("exit", self.name, self.step))
+
+    prof = Profiler()
+    prof.begin("engine.tick", 0)
+    prof.end()
+    assert calls == []
+    prof.annotate = Ann
+    prof.begin("engine.tick", 7)
+    prof.begin("engine.step")
+    prof.end()
+    prof.end()
+    assert calls == [("enter", "engine.tick", 7),
+                     ("enter", "engine.step", None),
+                     ("exit", "engine.step", None),
+                     ("exit", "engine.tick", 7)]
+    # a hook may decline (no device trace being recorded)
+    prof.annotate = lambda name, step: None
+    prof.begin("engine.tick", 8)
+    prof.end()
+    prof.annotate = None
+    prof.begin("engine.tick", 9)
+    prof.end()
+    assert len(calls) == 4
+    assert prof.report()["phases"]["engine.tick"]["count"] == 4
+
+
+def test_tick_percentiles_are_exact():
+    import numpy as np
+    from repro.obs import Profiler
+    rng = np.random.default_rng(3)
+    ticks = rng.exponential(1e-3, 10_000)       # past the first buffer
+    prof = Profiler()
+    for dt in ticks:
+        prof.add("engine.tick", float(dt))
+    prof.add("engine.step", 1.0)                 # not a histogram span
+    hist = prof.report()["hist"]
+    assert list(hist) == ["engine.tick"]
+    h = hist["engine.tick"]
+    assert h["n"] == len(ticks)
+    want = np.percentile(ticks * 1e6, [50, 90, 99])
+    assert [h["p50_us"], h["p90_us"], h["p99_us"]] == list(want)
+    assert h["max_us"] == ticks.max() * 1e6
+    assert np.array_equal(prof.samples("engine.tick"), ticks)
+
+
+def test_core_counters_match_the_array_shapes():
+    pytest.importorskip("jax")
+    B = 3
+    results = _batched("paper", engine="jax", n=60, B=B,
+                       obs=ObsConfig(profile=True))
+    prof = results[0].profile
+    S = len(make_scenario("paper", seed=0)["instances"])
+    ticks = prof["counts"]["core.ticks"]
+    assert ticks == prof["phases"]["engine.tick"]["count"] \
+        == prof["hist"]["engine.tick"]["n"]
+    # in: rem_g, rem_c, alloc_g, alloc_c [B, S] f64, avail [B, S] bool,
+    # t, t_ev [B] f64, live [B] bool; out: rem_g, rem_c [B, S] f64,
+    # started [B, S] bool, t_comp [B] f64, sid [B] i64
+    assert prof["counts"]["core.h2d_bytes"] \
+        == ticks * (4 * 8 * B * S + B * S + 2 * 8 * B + B)
+    assert prof["counts"]["core.d2h_bytes"] \
+        == ticks * (2 * 8 * B * S + B * S + 8 * B + 8 * B)
+    assert sum(r.n_events for r in results) <= ticks * B
+    parents = {n: p["parent"] for n, p in prof["phases"].items()}
+    for name in ("engine.step", "engine.events", "allocator.solve"):
+        assert parents[name] == "engine.tick"
+    assert parents["engine.tick"] is parents["engine.build"] \
+        is parents["engine.collect"] is None
+
+
+def test_spans_reach_the_device_profiler_trace(tmp_path):
+    jax = pytest.importorskip("jax")
+    results = None
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        results = _batched("paper", engine="jax", n=20, B=2,
+                           obs=ObsConfig(profile=True))
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(path))
+    ticks, names = [], set()
+    for plane in data.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                names.add(ev.name)
+                if ev.name == "engine.tick":
+                    ticks.append(dict(ev.stats)["step_num"])
+    assert {"engine.build", "engine.step", "engine.events", "core.h2d",
+            "core.d2h", "engine.collect"} <= names
+    n = results[0].profile["counts"]["core.ticks"]
+    assert sorted(ticks) == list(range(n))
+    # untraced, the hook opens nothing
+    from jax.profiler import TraceAnnotation
+    assert not TraceAnnotation.is_enabled()
 
 
 # --------------------------------------------------------------------------- #
