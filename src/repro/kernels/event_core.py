@@ -16,15 +16,20 @@ vectors become [B, S] blocks (B seeds of one scenario×method cell in
 lockstep, one fused device call per tick), and the same expressions are
 a Pallas TPU kernel in :mod:`repro.kernels.event_step` alongside
 :mod:`repro.kernels.alloc_active_set` (lane reductions over the padded
-instance dimension).
+instance dimension).  :func:`event_step_jax_packed` runs the batched
+step on one packed buffer each way, so a tick is one transfer in and one
+out.
 
 Like every module in this package, importing it requires jax; the
 simulator only imports it when ``engine="jax"`` is selected.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 INF = jnp.inf
 
@@ -85,10 +90,19 @@ def event_step_jax(rem_g: jax.Array, rem_c: jax.Array,
     replicas at their event budget) advance by ``dt = 0``.
 
     Returns ``(rem_g', rem_c', started, t_comp [B], sid [B])`` — the
-    single device round-trip per lockstep tick of ``Simulator.run_batch``.
-    This is the jnp form of the Pallas kernel in
-    :mod:`repro.kernels.event_step`; both evaluate the expressions of the
-    numpy batched core elementwise.
+    step of each lockstep tick of ``Simulator.run_batch``, whose operands
+    and results cross as one packed buffer each way
+    (:func:`event_step_jax_packed`).  This is the jnp form of the Pallas
+    kernel in :mod:`repro.kernels.event_step`; both evaluate the
+    expressions of the numpy batched core elementwise.
+
+    The ``maximum(·, 0)`` around each work product is exact (the product
+    is never negative where it is taken).  It keeps XLA:CPU from
+    contracting the product and its subtraction into a fused multiply-add,
+    which it does or not depending on how the surrounding program is
+    fused; so the step gives the same bits inside
+    :func:`event_step_jax_packed` as on its own, and on the CPU the
+    numpy core's.
     """
     with jax.named_scope("event_core.step"):
         t_col = t[:, None]
@@ -104,11 +118,57 @@ def event_step_jax(rem_g: jax.Array, rem_c: jax.Array,
         run_g = avail & gpu_need & (alloc_g > 0.0) & (dt > 0.0)
         stalled = avail & gpu_need & (alloc_g <= 0.0)
         tg = jnp.where(run_g, jnp.minimum(dt, rem_g / alloc_g), 0.0)
-        dg = jnp.where(run_g, alloc_g * tg, 0.0)
+        dg = jnp.where(run_g, jnp.maximum(alloc_g * tg, 0.0), 0.0)
         rg_new = rem_g - dg
         rem_dt = jnp.where(run_g, dt - tg, dt)
         cpu_ok = (avail & ~stalled & (rg_new <= 0.0) & (rem_dt > 0.0)
                   & (rem_c > 0.0) & (alloc_c > 0.0))
         tc = jnp.where(cpu_ok, jnp.minimum(rem_dt, rem_c / alloc_c), 0.0)
-        dc = jnp.where(cpu_ok, alloc_c * tc, 0.0)
+        dc = jnp.where(cpu_ok, jnp.maximum(alloc_c * tc, 0.0), 0.0)
         return rg_new, rem_c - dc, run_g | cpu_ok, t_comp, sid
+
+
+# A batched tick's packed layout, per row: in ``rem_g | rem_c | alloc_g |
+# alloc_c | avail`` (S lanes each) ``| t | t_ev | live``; out ``rem_g' |
+# rem_c' | started`` (S lanes each) ``| t_comp | sid``.  Flags travel as
+# 0.0/1.0 and ``sid`` as a float64 integer, both exact.
+def packed_widths(S: int):
+    """Row widths ``(in, out)`` of the packed buffers at ``S`` instances."""
+    return 5 * S + 3, 3 * S + 2
+
+
+def pack_step_inputs(buf: np.ndarray, rem_g, rem_c, alloc_g, alloc_c,
+                     avail, t, t_ev, live) -> np.ndarray:
+    """Copy the eight operands of a batched step into the host buffer
+    ``buf`` (float64 ``[B, 5S + 3]``); returns ``buf``."""
+    S = rem_g.shape[1]
+    for k, a in enumerate((rem_g, rem_c, alloc_g, alloc_c, avail)):
+        np.copyto(buf[:, k * S:(k + 1) * S], a)
+    for k, a in enumerate((t, t_ev, live), 5 * S):
+        np.copyto(buf[:, k], a)
+    return buf
+
+
+def unpack_step_outputs(res: np.ndarray):
+    """``(rem_g', rem_c', started, t_comp, sid)`` of a packed result read
+    back to the host: float64 views, a bool mask and int64 indices."""
+    S = (res.shape[1] - 2) // 3
+    return (res[:, :S], res[:, S:2 * S], res[:, 2 * S:3 * S] != 0.0,
+            res[:, 3 * S], res[:, 3 * S + 1].astype(np.int64))
+
+
+@functools.partial(jax.jit, static_argnames=("step",))
+def event_step_jax_packed(buf: jax.Array, *, step):
+    """One batched tick on one packed buffer each way: ``step`` (an
+    eight-operand step such as :func:`event_step_jax`) on the operands
+    sliced from the ``[B, 5S + 3]`` buffer ``buf``
+    (:func:`pack_step_inputs`), its five results concatenated into one
+    ``[B, 3S + 2]`` array of ``buf``'s dtype (:func:`unpack_step_outputs`)."""
+    S = (buf.shape[1] - 3) // 5
+    cols = [buf[:, k * S:(k + 1) * S] for k in range(5)]
+    rg, rc, started, t_comp, sid = step(
+        *cols[:4], cols[4] != 0.0, buf[:, 5 * S], buf[:, 5 * S + 1],
+        buf[:, 5 * S + 2] != 0.0)
+    f = buf.dtype
+    return jnp.concatenate([rg, rc, started.astype(f), t_comp[:, None],
+                            sid.astype(f)[:, None]], axis=1)

@@ -51,11 +51,13 @@ INF = float("inf")
 # by the Simulator per run).  The numpy/scalar cores' work is already
 # timed by the simulator's "engine.step" span; the jax/pallas cores split it
 # into two spans around the calls the unprofiled path makes anyway:
-# ``core.h2d`` — the call into the jitted program, which copies the host
-# arrays and enqueues the step — and ``core.d2h`` — the reads of its
-# outputs, which wait for the device and copy back.  They count
-# ``core.ticks`` (steps dispatched) and ``core.h2d_bytes`` /
-# ``core.d2h_bytes`` (the arrays passed and read back).  Profiling adds
+# ``core.h2d`` — packing the operands (batched cores) and the call into
+# the jitted program, which copies them in and enqueues the step — and
+# ``core.d2h`` — the reads of its outputs, which wait for the device and
+# copy back, and their unpacking.  They count ``core.ticks`` (steps
+# dispatched), ``core.h2d_transfers`` / ``core.d2h_transfers`` (arrays
+# passed and read back: one each a batched tick) and ``core.h2d_bytes``
+# / ``core.d2h_bytes`` (their bytes).  Profiling adds
 # no staging and no synchronisation: device time is the device trace's.
 
 
@@ -273,6 +275,7 @@ class JaxEventCore:
                 prof.end()
                 prof.add_count("core.ticks", 1)
                 # the arrays and the float64 time ``t``
+                prof.add_count("core.h2d_transfers", len(args) + 1)
                 prof.add_count("core.h2d_bytes",
                                sum(a.nbytes for a in args) + 8)
                 prof.begin("core.d2h")
@@ -280,6 +283,7 @@ class JaxEventCore:
             sid = int(d_sid)
             if prof is not None:
                 prof.end()
+                prof.add_count("core.d2h_transfers", 2)
                 prof.add_count("core.d2h_bytes",
                                d_best.nbytes + d_sid.nbytes)
         if not np.isfinite(best):
@@ -300,6 +304,7 @@ class JaxEventCore:
             if prof is not None:
                 prof.end()
                 # the arrays and the float64 step ``dt``
+                prof.add_count("core.h2d_transfers", len(args) + 1)
                 prof.add_count("core.h2d_bytes",
                                sum(a.nbytes for a in args) + 8)
                 prof.begin("core.d2h")
@@ -308,6 +313,7 @@ class JaxEventCore:
             cluster.head_started |= np.asarray(started)
             if prof is not None:
                 prof.end()
+                prof.add_count("core.d2h_transfers", 3)
                 prof.add_count("core.d2h_bytes",
                                rg.nbytes + rc.nbytes + started.nbytes)
 
@@ -461,8 +467,17 @@ class ScalarBatchedEventCore:
 
 class JaxBatchedEventCore:
     """jax-jitted fused [B, S] step (float64) — the accelerator-resident
-    growth path.  Discrete outcomes match the numpy batched core; event
-    times may differ by ulps (XLA multiply-add fusion)."""
+    growth path.  On the CPU its results are the numpy batched core's,
+    bit for bit; on a TPU, which emulates float64, discrete outcomes
+    match and event times may differ by ulps.
+
+    A tick crosses to the device and back once each way: the eight
+    operands are packed into one preallocated float64 host buffer, and
+    :func:`~repro.kernels.event_core.event_step_jax_packed` slices them
+    apart on the device, runs :meth:`_step_fn` on them and returns the
+    five results as one array, which is unpacked into the block (the
+    layout is :mod:`repro.kernels.event_core`'s).
+    """
 
     name = "jax"
     profiler = None
@@ -472,35 +487,51 @@ class JaxBatchedEventCore:
         from repro.kernels import event_core as kec
         self._jax = jax
         self._kernel = kec
+        self._shape = None
 
-    def _call(self, rg, rc, g, c, avail, t_vec, t_ev, can):
-        return self._kernel.event_step_jax(rg, rc, g, c, avail,
-                                           t_vec, t_ev, can)
+    def _step_fn(self):
+        """The eight-operand [B, S] step that the packed program runs;
+        looked up each tick, and the program's compile cache is keyed on
+        it."""
+        return self._kernel.event_step_jax
+
+    def _ensure_scratch(self, B: int, S: int) -> None:
+        if self._shape != (B, S):
+            self._shape = (B, S)
+            self._in = np.empty((B, self._kernel.packed_widths(S)[0]),
+                                np.float64)
+            self._avail = np.empty((B, S), bool)
 
     def step(self, block, t_vec, t_ev, can):
         prof = self.profiler
-        avail = block.head_mask & (block.reconfig_until <= t_vec[:, None])
-        args = (block.head_rem_g, block.head_rem_c,
-                block.alloc_g, block.alloc_c, avail, t_vec, t_ev, can)
+        kec = self._kernel
         with self._jax.enable_x64(True):
             if prof is not None:
                 prof.begin("core.h2d")
-            out = self._call(*args)
+            self._ensure_scratch(block.B, block.S)
+            avail = self._avail
+            np.less_equal(block.reconfig_until, t_vec[:, None], out=avail)
+            np.logical_and(avail, block.head_mask, out=avail)
+            buf = kec.pack_step_inputs(
+                self._in, block.head_rem_g, block.head_rem_c,
+                block.alloc_g, block.alloc_c, avail, t_vec, t_ev, can)
+            out = kec.event_step_jax_packed(buf, step=self._step_fn())
             if prof is not None:
                 prof.end()
                 prof.add_count("core.ticks", 1)
-                prof.add_count("core.h2d_bytes",
-                               sum(a.nbytes for a in args))
+                prof.add_count("core.h2d_transfers", 1)
+                prof.add_count("core.h2d_bytes", buf.nbytes)
                 prof.begin("core.d2h")
-            rg, rc, started, t_comp, sid = out
-            block.head_rem_g[...] = np.asarray(rg)
-            block.head_rem_c[...] = np.asarray(rc)
-            block.head_started |= np.asarray(started)
-            ret = np.asarray(t_comp), np.asarray(sid, np.int64)
+            res = np.asarray(out)
+            rg, rc, started, t_comp, sid = kec.unpack_step_outputs(res)
+            block.head_rem_g[...] = rg
+            block.head_rem_c[...] = rc
+            block.head_started |= started
+            ret = t_comp, sid
             if prof is not None:
                 prof.end()
-                prof.add_count("core.d2h_bytes",
-                               sum(o.nbytes for o in out))
+                prof.add_count("core.d2h_transfers", 1)
+                prof.add_count("core.d2h_bytes", res.nbytes)
             return ret
 
 
@@ -511,7 +542,8 @@ class PallasBatchedEventCore(JaxBatchedEventCore):
     core runs the kernel in interpret mode, which keeps float64 and
     therefore the same discrete-outcome bar as the jax core.  On a TPU it
     refuses to construct rather than cast the state or interpret the
-    kernel on the chip.  See :mod:`repro.kernels.event_step`.
+    kernel on the chip.  See :mod:`repro.kernels.event_step`.  The
+    packing each way is the jax core's.
     """
 
     name = "pallas"
@@ -527,10 +559,9 @@ class PallasBatchedEventCore(JaxBatchedEventCore):
         from repro.kernels import event_step as kes
         self._step_kernel = kes
 
-    def _call(self, rg, rc, g, c, avail, t_vec, t_ev, can):
-        return self._step_kernel.event_step(rg, rc, g, c, avail,
-                                            t_vec, t_ev, can,
-                                            interpret=True)
+    def _step_fn(self):
+        # interpret mode is event_step's default
+        return self._step_kernel.event_step
 
 
 BATCH_ENGINES = ("numpy", "scalar", "jax", "pallas")

@@ -222,6 +222,20 @@ def test_run_batch_jax_and_pallas_cores(engine):
             [r.target_sid for r in b.requests]
 
 
+@pytest.mark.parametrize("family", ("paper", "dense-urban", "flash-crowd"))
+def test_run_batch_jax_core_is_the_numpy_core_on_cpu(family):
+    """On XLA:CPU the jax step evaluates the numpy core's IEEE-754
+    expressions with no fused multiply-add: every statistic and finish
+    time of a batch is the numpy batch's, bit for bit."""
+    jax = pytest.importorskip("jax")
+    if jax.default_backend() != "cpu":
+        pytest.skip("the bit-for-bit bar holds for XLA:CPU")
+    want = [_fingerprint(r) for r in _run_batch(family, BATCH_SEEDS)]
+    got = [_fingerprint(r) for r in
+           _run_batch(family, BATCH_SEEDS, engine="jax")]
+    assert got == want
+
+
 def test_pallas_core_refused_on_tpu(monkeypatch):
     """Mosaic has no float64: on a TPU the pallas engine refuses at
     construction and names the jax engine, instead of casting its state
@@ -233,6 +247,82 @@ def test_pallas_core_refused_on_tpu(monkeypatch):
         make_batched_event_core("pallas")
     with pytest.raises(RuntimeError, match="float64"):
         Simulator(paper_scenario(), engine="pallas")
+
+
+PACKED_CASES = {          # case -> B
+    "random": 32, "b_not_multiple_of_8": 13, "inf_heads": 16,
+    "stalled_stages": 16, "dead_rows": 11, "unavailable_rows": 9}
+
+
+def _step_inputs(case: str, B: int, S: int = 18, seed: int = 7):
+    """A [B, S] block state for one tick, shaped by ``case``."""
+    rng = np.random.default_rng([seed, B])
+    rem_g = rng.uniform(0.0, 5.0, (B, S)) * (rng.random((B, S)) < 0.7)
+    rem_c = rng.uniform(0.0, 2.0, (B, S)) * (rng.random((B, S)) < 0.8)
+    alloc_g = rng.uniform(0.1, 2.0, (B, S))
+    alloc_c = rng.uniform(0.1, 2.0, (B, S))
+    head_mask = rng.random((B, S)) < 0.8
+    reconfig_until = np.where(rng.random((B, S)) < 0.1, 1e9, 0.0)
+    t = rng.uniform(0.0, 100.0, B)
+    t_ev = t + rng.exponential(1.0, B)
+    live = np.ones(B, bool)
+    if case == "inf_heads":
+        t_ev[::2] = np.inf                  # no pending event
+        rem_c[1::3, :4] = np.inf            # a head that never finishes
+    elif case == "stalled_stages":
+        alloc_g[rng.random((B, S)) < 0.4] = 0.0
+        alloc_c[rng.random((B, S)) < 0.4] = 0.0
+    elif case == "dead_rows":
+        live[::3] = False
+    elif case == "unavailable_rows":
+        head_mask[1::2] = False
+    return dict(head_rem_g=rem_g, head_rem_c=rem_c,
+                alloc_g=alloc_g, alloc_c=alloc_c, head_mask=head_mask,
+                reconfig_until=reconfig_until,
+                head_started=np.zeros((B, S), bool)), t, t_ev, live
+
+
+def _bits(a):
+    return np.asarray(a, np.float64).view(np.uint64)
+
+
+@pytest.mark.parametrize("engine", ("jax", "pallas"))
+@pytest.mark.parametrize("case", sorted(PACKED_CASES))
+def test_packed_step_is_the_unpacked_step_bit_for_bit(engine, case):
+    """One packed buffer each way per tick: the block the device core
+    leaves and the ``(t_comp, sid)`` it returns are, bit for bit, what
+    its eight-operand step gives on the same block."""
+    import types
+    jax = pytest.importorskip("jax")
+    from repro.sim.event_core import make_batched_event_core
+    state, t, t_ev, live = _step_inputs(case, PACKED_CASES[case])
+    avail = state["head_mask"] & (state["reconfig_until"] <= t[:, None])
+    core = make_batched_event_core(engine)
+    with jax.enable_x64(True):
+        want = [np.asarray(o) for o in core._step_fn()(
+            state["head_rem_g"], state["head_rem_c"], state["alloc_g"],
+            state["alloc_c"], avail, t, t_ev, live)]
+    B, S = state["head_rem_g"].shape
+    block = types.SimpleNamespace(B=B, S=S, **{k: np.copy(v) for k, v in
+                                               state.items()})
+    t_comp, sid = core.step(block, t, t_ev, live)
+    assert np.array_equal(_bits(block.head_rem_g), _bits(want[0]))
+    assert np.array_equal(_bits(block.head_rem_c), _bits(want[1]))
+    assert block.head_started.dtype == np.bool_
+    assert np.array_equal(block.head_started, want[2])
+    assert t_comp.dtype == np.float64 and sid.dtype == np.int64
+    assert np.array_equal(_bits(t_comp), _bits(want[3]))
+    assert np.array_equal(sid, want[4])
+    # inputs the step does not write are left as they were
+    for name in ("alloc_g", "alloc_c", "head_mask", "reconfig_until"):
+        assert np.array_equal(getattr(block, name), state[name])
+    if case == "unavailable_rows":
+        assert np.all(np.isinf(t_comp[1::2])) and not sid[1::2].any()
+    if case == "dead_rows":
+        dead = ~live
+        assert np.array_equal(block.head_rem_g[dead],
+                              state["head_rem_g"][dead])
+        assert not block.head_started[dead].any()
 
 
 def test_run_batch_unknown_engine_rejected():

@@ -432,13 +432,13 @@ def test_core_counters_match_the_array_shapes():
     ticks = prof["counts"]["core.ticks"]
     assert ticks == prof["phases"]["engine.tick"]["count"] \
         == prof["hist"]["engine.tick"]["n"]
-    # in: rem_g, rem_c, alloc_g, alloc_c [B, S] f64, avail [B, S] bool,
-    # t, t_ev [B] f64, live [B] bool; out: rem_g, rem_c [B, S] f64,
-    # started [B, S] bool, t_comp [B] f64, sid [B] i64
-    assert prof["counts"]["core.h2d_bytes"] \
-        == ticks * (4 * 8 * B * S + B * S + 2 * 8 * B + B)
-    assert prof["counts"]["core.d2h_bytes"] \
-        == ticks * (2 * 8 * B * S + B * S + 8 * B + 8 * B)
+    # one packed float64 buffer each way a tick: in rem_g, rem_c, alloc_g,
+    # alloc_c, avail [B, S] and t, t_ev, live [B]; out rem_g, rem_c,
+    # started [B, S] and t_comp, sid [B]
+    assert prof["counts"]["core.h2d_transfers"] \
+        == prof["counts"]["core.d2h_transfers"] == ticks
+    assert prof["counts"]["core.h2d_bytes"] == ticks * 8 * B * (5 * S + 3)
+    assert prof["counts"]["core.d2h_bytes"] == ticks * 8 * B * (3 * S + 2)
     assert sum(r.n_events for r in results) <= ticks * B
     parents = {n: p["parent"] for n, p in prof["phases"].items()}
     for name in ("engine.step", "engine.events", "allocator.solve"):

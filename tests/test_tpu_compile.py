@@ -70,3 +70,20 @@ def test_alloc_active_set_compiles_for_v5e(one_chip, monkeypatch, N, S):
         block, block, block, sds((N,), jnp.float32),
         sds((N, S), jnp.bool_)).compile()
     assert _tpu_kernel_count(compiled) >= 1
+
+
+
+@pytest.mark.parametrize("B", (32, 256))
+def test_packed_event_step_compiles_for_v5e(one_chip, B):
+    """The jax engine's tick at the benchmark's shapes (S = 18, float64):
+    one packed buffer in, one packed array out, one program."""
+    from repro.kernels import event_core as kec
+    w_in, w_out = kec.packed_widths(18)
+    buf = jax.ShapeDtypeStruct((B, w_in), jnp.float64, sharding=one_chip)
+    with jax.enable_x64(True):
+        lowered = kec.event_step_jax_packed.lower(buf,
+                                                  step=kec.event_step_jax)
+        compiled = lowered.compile()
+    out = lowered.out_info
+    assert out.shape == (B, w_out) and out.dtype == jnp.float64
+    assert compiled.memory_analysis() is not None
