@@ -17,7 +17,7 @@ from repro.analysis.core import Finding, ModuleInfo, Rule, register
 
 #: hot-path modules under the contract (rel to the scan root)
 OBS_GUARD_SCOPE: Set[str] = {"sim/engine.py", "sim/cluster.py",
-                             "sim/event_core.py"}
+                             "sim/event_core.py", "core/controller.py"}
 
 #: a call receiver is an obs hook when its final attribute (or its bare
 #: name) is one of these — self.trace.emit, observer.metrics.series,
@@ -84,13 +84,14 @@ def _in_branch(parent: ast.If, node: ast.AST, mod: ModuleInfo) -> bool:
 
 @register
 class ObsGuard(Rule):
-    """Obs hooks on engine/cluster hot paths must be ``None``-guarded."""
+    """Obs hooks on engine, cluster and controller hot paths must be
+    ``None``-guarded."""
 
     name = "obs-guard"
     description = ("zero-overhead-when-off: trace/metrics/profiler "
-                   "calls in sim/engine.py, sim/cluster.py and "
-                   "sim/event_core.py must sit inside an "
-                   "`if <recv> is not None` guard")
+                   "calls in sim/engine.py, sim/cluster.py, "
+                   "sim/event_core.py and core/controller.py must sit "
+                   "inside an `if <recv> is not None` guard")
     hint = ("wrap the call: `if <receiver> is not None: <receiver>...`"
             " — obs-off runs carry None recorders and must not pay "
             "(or crash on) the hook")

@@ -72,7 +72,7 @@ class HAFPlacement:
 
     @staticmethod
     def decide_group(policies: Sequence["HAFPlacement"],
-                     snaps: Sequence[EpochSnapshot]
+                     snaps: Sequence[EpochSnapshot], prof=None
                      ) -> List[Optional[MigrationAction]]:
         """One batched placement decision for B compatible replicas.
 
@@ -81,11 +81,23 @@ class HAFPlacement:
         completion call each), then ONE padded ``[B, C, F]`` critic
         evaluation scores every replica's shortlist+no-migration options.
         The critic forward is batch-shape invariant, so each replica's
-        action is bit-identical to deciding it alone.
+        action is bit-identical to deciding it alone.  ``prof`` (a
+        ``repro.obs.Profiler``) opens the ``epoch.candidates``,
+        ``epoch.shortlist`` and ``epoch.critic`` spans and counts groups,
+        candidates and vetoes.
         """
         B = len(policies)
         out: List[Optional[MigrationAction]] = [None] * B
+        if prof is not None:
+            prof.add_count("epoch.groups", 1)
+            prof.begin("epoch.candidates")
         m_ks = [candidate_actions(s) for s in snaps]
+        if prof is not None:
+            prof.end()
+            # every M_k holds the no-migration option besides the moves
+            prof.add_count("epoch.candidates",
+                           sum(len(m) - 1 for m in m_ks))
+            prof.begin("epoch.shortlist")
         # one shortlist_batch call per compatible agent group: agents
         # sharing a config batch_key (same K) are interchangeable; anything
         # else — mixed direct calls, stateful LLM agents — dispatches per
@@ -120,6 +132,8 @@ class HAFPlacement:
             for i, row in zip(idxs, rows):
                 shortlists[i] = row
                 degraded[i] = reason
+        if prof is not None:
+            prof.end()
         gated = []                     # (index, options) for critic scoring
         for i, (pol, shortlist) in enumerate(zip(policies, shortlists)):
             pol.last_shortlist = [a for a in shortlist if a is not None]
@@ -145,6 +159,9 @@ class HAFPlacement:
         for item in gated:
             fp = policies[item[0]].critic.fingerprint()
             by_critic.setdefault(fp, []).append(item)
+        if prof is not None and by_critic:
+            prof.begin("epoch.critic")
+        vetoed = 0
         for group in by_critic.values():
             critic = policies[group[0][0]].critic
             choices, score_rows = critic.select_batch(
@@ -159,6 +176,7 @@ class HAFPlacement:
                     if len(options) > 1:
                         pol.last_margin = float(
                             max(scores) - scores[none_idx])
+                        vetoed += 1
                     continue
                 # optional hysteresis: require a margin over no-migration
                 chosen_idx = options.index(choice)
@@ -166,8 +184,12 @@ class HAFPlacement:
                     scores[chosen_idx] - scores[none_idx])
                 if scores[chosen_idx] < scores[none_idx] \
                         + pol.min_score_margin:
+                    vetoed += 1
                     continue
                 out[i] = choice
+        if prof is not None and by_critic:
+            prof.end()
+            prof.add_count("epoch.vetoed", vetoed)
         return out
 
 

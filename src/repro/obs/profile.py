@@ -30,7 +30,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 #: spans whose every duration is kept for exact percentiles
-HIST_SPANS = ("engine.tick",)
+HIST_SPANS = ("engine.tick", "epoch.decide")
 #: percentiles the report gives for each histogram span
 PERCENTILES = (50, 90, 99)
 

@@ -29,12 +29,14 @@ Two drivers share one per-replica event machine (:class:`_Replica`):
     Discrete outcomes are identical to running each seed solo.
 
 The slow timescale is batched the same way: an epoch event only *stages*
-its snapshot (``_Replica.pending_epoch``); the driver collects every
+its index (``_Replica.pending_epoch``); the driver collects every
 replica at an epoch boundary this tick and hands them to
-:func:`dispatch_epoch_decisions`, which groups compatible policies (by
-``batch_key()``) into ONE ``decide_group`` call — the HAF stack stacks
-candidate features ``[B, C, F]`` and runs the critic once per group —
-then commits each replica's action (``_Replica.commit_epoch``).  The
+:func:`dispatch_epoch_decisions`, which builds each replica's snapshot
+from the epoch that closes, then clears that epoch's window, groups
+compatible policies (by ``batch_key()``) into ONE ``decide_group`` call —
+the HAF stack stacks candidate features ``[B, C, F]`` and runs the critic
+once per group — then commits each replica's action
+(``_Replica.commit_epoch``).  The
 solo driver routes single epochs through the same dispatcher, so batched
 and solo decisions are the same code on the same inputs.
 """
@@ -373,10 +375,11 @@ class _Replica:
         # that only READS simulation state — the bit-identity contract)
         self.trace = None
         self.metrics = None
-        self.b = 0
-        # epoch boundary reached this event: (k, snapshot) awaiting the
-        # placement decision (dispatched by the driver, possibly batched)
-        self.pending_epoch: Optional[Tuple[int, EpochSnapshot]] = None
+        self.b = 0                    # index in the batch (run_batch sets it)
+        # epoch boundary reached this event: its index k, awaiting the
+        # snapshot and the placement decision (dispatched by the driver,
+        # possibly batched)
+        self.pending_epoch: Optional[int] = None
         allocation.allocate(self.cluster, self.t)
         self.dirty: set = set()
         self.last_full = 0.0
@@ -615,10 +618,9 @@ class _Replica:
         elif kind == "epoch":
             # the decision is the driver's: it collects every replica that
             # reached an epoch boundary this tick and dispatches one
-            # (possibly batched) decide, then calls commit_epoch
-            k: int = payload
-            self.close_epoch_window(self.current_rec)
-            self.pending_epoch = (k, self.build_snapshot(k))
+            # (possibly batched) decide, which builds the snapshot from
+            # the closing window before clearing it
+            self.pending_epoch = payload
         elif kind == "mig_done":
             self.mark(payload)   # availability flip triggers realloc
         elif kind == "outage":
@@ -703,12 +705,13 @@ class _Replica:
                     cluster.node_drain_until[m] = 0.0
 
     def commit_epoch(self, k: int, snap: EpochSnapshot,
-                     action: Optional[MigrationAction]) -> None:
+                     action: Optional[MigrationAction]) -> bool:
         """Apply the placement decision for epoch ``k`` (Eq. 12 commit).
 
         Runs exactly the post-decide tail the epoch event used to handle
         inline: feasibility gate, migration apply + reconfiguration window
         (outage-aware), EpochRecord bookkeeping, hook, full-realloc mark.
+        Returns whether a migration was committed.
         """
         cluster, t, sc = self.cluster, self.t, self.sc
         shortlist = getattr(self.placement, "last_shortlist", [])
@@ -784,6 +787,7 @@ class _Replica:
             self.epoch_hook(self.current_rec, cluster)
         self.pending_epoch = None
         self.dirty.update(range(cluster.N))
+        return action is not None
 
     def realloc_nodes(self):
         """Post-event reallocation scope: ``None`` = full re-solve,
@@ -834,20 +838,40 @@ class _Replica:
         return res
 
 
-def dispatch_epoch_decisions(reps: Sequence[_Replica]) -> None:
-    """Decide + commit the pending epoch of every given replica.
+def dispatch_epoch_decisions(reps: Sequence[_Replica], prof=None,
+                             settle: Optional[Callable] = None) -> None:
+    """Snapshot, decide + commit the pending epoch of every given replica.
 
-    The slow-timescale analogue of the ``[B, S]`` event step: policies
-    exposing ``batch_key()`` / ``decide_group()`` and sharing a key are
-    decided by ONE batched call (HAF stacks candidate features and runs
-    the critic once for the whole group); everything else — plain
-    baselines, scripted policies, LLM-backed agents keyed per instance —
-    falls back to per-replica ``decide``.  Grouping must not change
-    outcomes: ``decide_group`` is batch-shape invariant and ``decide`` is
-    its B=1 view, so a replica's committed action is identical however
-    its epoch boundary lands in a batch.
+    Each replica's snapshot describes the epoch that closes here (its
+    per-service arrival rates and per-class fulfilment); its window is
+    cleared only after the snapshot is built.  The slow-timescale
+    analogue of the ``[B, S]`` event step: policies exposing
+    ``batch_key()`` / ``decide_group()`` and sharing a key are decided by
+    ONE batched call (HAF stacks candidate features and runs the critic
+    once for the whole group); everything else — plain baselines,
+    scripted policies, LLM-backed agents keyed per instance — falls back
+    to per-replica ``decide``.  Grouping must not change outcomes:
+    ``decide_group`` is batch-shape invariant and ``decide`` is its B=1
+    view, so a replica's committed action is identical however its epoch
+    boundary lands in a batch.  ``settle(rep)``, when given, runs for
+    every replica after the commits (the batched driver's deferred
+    post-event tail).  ``prof`` (a ``repro.obs.Profiler``) opens the
+    ``epoch.snapshot`` and ``epoch.commit`` spans and counts decisions.
     """
-    items = [(rep,) + rep.pending_epoch for rep in reps]
+    if prof is not None:
+        prof.begin("epoch.snapshot")
+    items = []
+    for rep in reps:
+        k = rep.pending_epoch
+        snap = rep.build_snapshot(k)
+        rep.close_epoch_window(rep.current_rec)
+        items.append((rep, k, snap))
+    if prof is not None:
+        prof.end()
+        prof.add_count("epoch.decisions", len(items))
+        prof.add_count("epoch.rate_services", sum(
+            sum(1 for r in snap.arrival_rate.values() if r > 0)
+            for _, _, snap in items))
     actions: List[Optional[MigrationAction]] = [None] * len(items)
     groups: Dict[tuple, List[int]] = {}
     for i, (rep, k, snap) in enumerate(items):
@@ -863,11 +887,26 @@ def dispatch_epoch_decisions(reps: Sequence[_Replica]) -> None:
     for (pol_cls, _), idxs in groups.items():
         decided = pol_cls.decide_group(
             [items[i][0].placement for i in idxs],
-            [items[i][2] for i in idxs])
+            [items[i][2] for i in idxs], prof=prof)
         for i, action in zip(idxs, decided):
             actions[i] = action
+    if prof is not None:
+        prof.begin("epoch.commit")
+    committed = 0
     for (rep, k, snap), action in zip(items, actions):
-        rep.commit_epoch(k, snap, action)
+        committed += rep.commit_epoch(k, snap, action)
+    if settle is not None:
+        for rep in reps:
+            settle(rep)
+    if prof is not None:
+        prof.end()
+        proposed = sum(1 for a in actions if a is not None)
+        prof.add_count("epoch.proposed", proposed)
+        prof.add_count("epoch.committed", committed)
+        prof.add_count("epoch.infeasible", proposed - committed)
+        prof.add_count("epoch.degraded", sum(
+            1 for rep in reps
+            if getattr(rep.placement, "last_degraded", None) is not None))
 
 
 def _realize_policies(spec, B: int, what: str) -> List:
@@ -994,7 +1033,7 @@ class Simulator:
                 if pending:
                     if prof is not None:
                         prof.begin("epoch.decide")
-                    dispatch_epoch_decisions((rep,))
+                    dispatch_epoch_decisions((rep,), prof)
                     if prof is not None:
                         prof.end()
 
@@ -1002,6 +1041,9 @@ class Simulator:
                 nodes = rep.realloc_nodes()
                 if nodes is None or nodes:
                     if prof is not None:
+                        prof.add_count("allocator.problems",
+                                       cluster.N if nodes is None
+                                       else len(nodes))
                         prof.begin("allocator.solve")
                     if nodes is None:
                         allocation.allocate(cluster, rep.t)
@@ -1082,6 +1124,8 @@ class Simulator:
                          allocations[b], rr_dispatch, hooks[b],
                          retain_requests=retain_requests)
                 for b in range(B)]
+        for b, rep in enumerate(reps):
+            rep.b = b
         block = ClusterBlock([rep.cluster for rep in reps])
         core = make_batched_event_core(engine_name)
         if observer is not None:
@@ -1089,7 +1133,6 @@ class Simulator:
             for b, rep in enumerate(reps):
                 rep.trace = observer.trace
                 rep.metrics = metrics
-                rep.b = b
                 rep.cluster.trace = observer.trace
                 rep.cluster.trace_b = b
         # the cross-replica allocation gather is exact only for the
@@ -1106,11 +1149,12 @@ class Simulator:
         node_lists: List = [()] * B
         state = {"any_alloc": False}
 
-        def settle(b: int, rep: _Replica) -> None:
+        def settle(rep: _Replica) -> None:
             """Post-event tail of one replica: drops, realloc scope, next
             event time.  Runs right after the event for ordinary events,
             or after the batched decide for epoch boundaries — either way
             at the same point of the replica's own event order."""
+            b = rep.b
             rep.cleanup_drops()
             nodes = rep.realloc_nodes()
             if nodes == ():
@@ -1121,6 +1165,9 @@ class Simulator:
             else:
                 # per replica-event: timed, but no span of its own
                 if prof is not None:
+                    prof.add_count("allocator.problems",
+                                   rep.cluster.N if nodes is None
+                                   else len(nodes))
                     _t0 = perf_counter()
                 if nodes is None:
                     rep.allocation.allocate(rep.cluster, rep.t)
@@ -1188,7 +1235,7 @@ class Simulator:
                         if rep.pending_epoch is not None:
                             at_epoch.append(b)  # decide after the sweep
                             continue
-                    settle(b, rep)
+                    settle(rep)
                 if prof is not None:
                     prof.end()
 
@@ -1197,13 +1244,15 @@ class Simulator:
                     # boundary this tick, then their deferred settle
                     if prof is not None:
                         prof.begin("epoch.decide")
-                    dispatch_epoch_decisions([reps[b] for b in at_epoch])
-                    for b in at_epoch:
-                        settle(b, reps[b])
+                    dispatch_epoch_decisions([reps[b] for b in at_epoch],
+                                             prof, settle)
                     if prof is not None:
                         prof.end()
                 if state["any_alloc"]:
                     if prof is not None:
+                        prof.add_count("allocator.problems", sum(
+                            rep.cluster.N if nodes is None else len(nodes)
+                            for rep, nodes in zip(reps, node_lists)))
                         prof.begin("allocator.solve")
                     deadline_allocate_block(block, t_vec, node_lists)
                     if prof is not None:

@@ -447,6 +447,183 @@ def test_core_counters_match_the_array_shapes():
         is parents["engine.collect"] is None
 
 
+# --------------------------------------------------------------------------- #
+# the epoch layer: spans under epoch.decide, counters that agree with the
+# results, and the allocator's problem count
+# --------------------------------------------------------------------------- #
+EPOCH_SPANS = ("epoch.snapshot", "epoch.candidates", "epoch.shortlist",
+               "epoch.critic", "epoch.commit")
+
+
+@pytest.fixture(scope="module")
+def critic_path(tmp_path_factory):
+    import numpy as np
+    from repro.core.critic import train_critic
+    from repro.core.features import FEATURE_DIM
+    rng = np.random.default_rng(1)
+    samples = [(rng.normal(size=FEATURE_DIM).astype(np.float32),
+                rng.uniform(size=3).astype(np.float32),
+                np.ones(3, np.float32)) for _ in range(40)]
+    path = tmp_path_factory.mktemp("critic") / "critic.json"
+    train_critic(samples, epochs=30, hidden=16, seed=0).save(str(path))
+    return str(path)
+
+
+def _haf(driver, critic, obs=None, n=120, B=3):
+    """HAF on the paper deployment, agent only or critic-gated, solo or
+    batched; returns the replicas' results."""
+    sc = make_scenario("paper", seed=0)
+    workloads = [workload_for(sc, seed=1 + s, n_ai_requests=n)[0]
+                 for s in range(B)]
+    sim = Simulator(sc, drop_expired=True)
+    if driver == "solo":
+        placement, allocation, rr = make_method("haf", critic_path=critic)
+        return [sim.run(workloads[0], placement, allocation,
+                        rr_dispatch=rr, obs=obs)]
+    return sim.run_batch(
+        workloads, lambda b: make_method("haf", critic_path=critic)[0],
+        lambda b: make_method("haf", critic_path=critic)[1], obs=obs)
+
+
+DRIVERS_AND_CRITICS = pytest.mark.parametrize(
+    "driver,with_critic", [("solo", False), ("solo", True),
+                           ("batched", False), ("batched", True)])
+
+
+@DRIVERS_AND_CRITICS
+def test_epoch_spans_nest_under_decide(driver, with_critic, critic_path):
+    results = _haf(driver, critic_path if with_critic else None,
+                   obs=ObsConfig(profile=True))
+    prof = results[0].profile
+    parents = {n: p["parent"] for n, p in prof["phases"].items()}
+    assert parents["epoch.decide"] == "engine.tick"
+    for name in EPOCH_SPANS:
+        if name == "epoch.critic" and not with_critic:
+            assert name not in parents
+            continue
+        assert parents[name] == "epoch.decide", name
+    decide = prof["phases"]["epoch.decide"]
+    assert prof["hist"]["epoch.decide"]["n"] == decide["count"] > 0
+    assert decide["total_s"] >= sum(prof["phases"][n]["total_s"]
+                                    for n in EPOCH_SPANS if n in parents)
+
+
+@DRIVERS_AND_CRITICS
+def test_epoch_counters_agree_with_the_results(driver, with_critic,
+                                               critic_path):
+    results = _haf(driver, critic_path if with_critic else None,
+                   obs=ObsConfig(profile=True))
+    counts = results[0].profile["counts"]
+    decisions = sum(len(r.epochs) for r in results)
+    committed = sum(len(r.migrations) for r in results)
+    assert counts["epoch.decisions"] == decisions > 0
+    assert counts["epoch.committed"] == committed > 0
+    assert counts["epoch.proposed"] >= \
+        counts["epoch.committed"] + counts["epoch.infeasible"]
+    assert 1 <= counts["epoch.groups"] <= decisions
+    assert counts["epoch.candidates"] >= counts["epoch.proposed"]
+    assert counts["epoch.degraded"] == sum(
+        r.summary()["degraded_decisions"] for r in results) == 0
+    # a decision is proposed, vetoed by the critic, or left alone
+    if with_critic:
+        assert counts["epoch.proposed"] + counts["epoch.vetoed"] \
+            <= decisions
+    else:
+        assert "epoch.vetoed" not in counts
+    # the snapshots carry the closing epoch's traffic
+    assert counts["epoch.rate_services"] > 0
+    assert counts["epoch.rate_services"] == sum(
+        sum(1 for v in e.snapshot.arrival_rate.values() if v > 0)
+        for r in results for e in r.epochs)
+    assert counts["allocator.problems"] > 0
+
+
+def test_epoch_degraded_counter_counts_the_fallbacks():
+    from repro.core.agent import ExternalLLMAgent, make_agent
+    from repro.core.controller import HAFPlacement
+    from repro.sim.engine import DeadlineAwareAllocation
+    calls = []
+
+    def flaky(prompt):                   # every other reply is garbage
+        calls.append(1)
+        return "I refuse." if len(calls) % 2 else '["no-migration"]'
+
+    placement = HAFPlacement(ExternalLLMAgent(flaky, name="flaky"),
+                             fallback_agent=make_agent("qwen3-32b-sim"))
+    sc = make_scenario("paper", seed=0)
+    reqs, _ = workload_for(sc, seed=1, n_ai_requests=120)
+    res = Simulator(sc).run(reqs, placement, DeadlineAwareAllocation(),
+                            obs=ObsConfig(profile=True))
+    counts = res.profile["counts"]
+    assert counts["epoch.degraded"] == sum(res.degraded.values()) \
+        == res.summary()["degraded_decisions"] > 0
+    assert counts["epoch.decisions"] == len(res.epochs)
+
+
+@pytest.mark.parametrize("driver,method", [
+    ("solo", "haf-static"), ("batched", "haf-static"),
+    ("batched", "lyapunov")])
+def test_allocator_problems_count_every_node_solved(monkeypatch, driver,
+                                                    method):
+    """A full re-solve counts every node, a partial one the nodes it
+    names: counted here at the allocator's own entry points, less the
+    full solve each replica makes when it is built, before any tick."""
+    from repro.sim import engine
+    seen = []
+    n_nodes = len(make_scenario("paper", seed=0)["nodes"])
+
+    def problems(nodes):
+        return n_nodes if nodes is None else len(nodes)
+
+    real_block = engine.deadline_allocate_block
+
+    def block(blk, t_vec, node_lists):
+        seen.append(sum(problems(nodes) for nodes in node_lists))
+        return real_block(blk, t_vec, node_lists)
+
+    monkeypatch.setattr(engine, "deadline_allocate_block", block)
+
+    def counted(allocation):
+        real = allocation.allocate
+
+        def allocate(cluster, t, nodes=None):
+            seen.append(problems(nodes))
+            return real(cluster, t, nodes)
+        allocation.allocate = allocate
+        return allocation
+
+    obs = ObsConfig(profile=True)
+    sc = make_scenario("paper", seed=0)
+    workloads = [workload_for(sc, seed=1 + s, n_ai_requests=60)[0]
+                 for s in range(3)]
+    if driver == "solo":
+        workloads = workloads[:1]
+        placement, allocation, rr = make_method(method)
+        res = Simulator(sc).run(workloads[0], placement, counted(allocation),
+                                rr_dispatch=rr, obs=obs)
+    else:
+        allocations = [counted(make_method(method)[1]) for _ in workloads]
+        res, *_ = Simulator(sc).run_batch(
+            workloads, lambda b: make_method(method)[0],
+            allocations, rr_dispatch=make_method(method)[2], obs=obs)
+    built = n_nodes * len(workloads)
+    assert res.profile["counts"]["allocator.problems"] == sum(seen) - built
+    assert sum(seen) > built
+
+
+@pytest.mark.parametrize("driver", ["solo", "batched"])
+def test_profiled_critic_gated_haf_is_bit_identical(driver, critic_path):
+    """The invariance tests above run agent-only HAF; the critic's span
+    and veto count sit on the critic-gated path."""
+    off = _haf(driver, critic_path)
+    on = _haf(driver, critic_path, obs=ObsConfig(profile=True))
+    assert [_fingerprint(r) for r in off] == [_fingerprint(r) for r in on]
+    assert [[(e.epoch, e.snapshot.arrival_rate, e.snapshot.recent_fulfill)
+             for e in r.epochs] for r in off] == \
+        [[(e.epoch, e.snapshot.arrival_rate, e.snapshot.recent_fulfill)
+          for e in r.epochs] for r in on]
+
+
 def test_spans_reach_the_device_profiler_trace(tmp_path):
     jax = pytest.importorskip("jax")
     results = None
