@@ -2,10 +2,9 @@
 solve across implementations and fleet sizes.
 
 The paper's allocator reacts to per-event demand in milliseconds on one
-node; the Pallas kernel batches the solve across the whole fleet in one
-device call (TPU-native scale-out).  On this CPU container the kernel runs
-in interpret mode, so its wall time is NOT meaningful — the structural
-claim (one call, [N,S] batched) is; the numpy/jax rows are real.
+node; ``allocate_cluster`` vmaps the solve across the whole fleet in one
+jitted call ([N, S] batched).  Times are host wall-clock on whatever
+backend JAX runs on.
 """
 from __future__ import annotations
 

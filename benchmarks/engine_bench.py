@@ -16,7 +16,7 @@ Eight sections recorded to ``BENCH_pr7.json``:
     ``batch_seeds`` seeds per simulation) vs process-parallel workers:
     end-to-end wall time including worker startup and scenario builds,
   * profile — the ``repro.obs`` phase profiler over the batched paper
-    family per backend (numpy / jax / pallas): per-phase wall-clock with
+    family per backend (numpy / jax): per-phase wall-clock with
     host↔device transfer (``core.h2d`` + ``core.d2h``) accounted
     separately from kernel time,
   * pr4_comparison — obs-off batched HAF throughput vs the PR-4 record:
@@ -302,10 +302,10 @@ def bench_solo_paper(n_requests: int) -> Dict:
 # profile: repro.obs phase accounting per backend (PR-6)
 # --------------------------------------------------------------------------- #
 def bench_profile(n_requests: int, B: int = 8,
-                  engines=("numpy", "jax", "pallas")) -> Dict:
+                  engines=("numpy", "jax")) -> Dict:
     """Per-phase wall-clock for the batched paper family on each backend.
 
-    The device engines (jax, pallas) split the step's round trip into
+    The device engine (jax) splits the step's round trip into
     ``core.h2d`` (the call: copy in and enqueue) and ``core.d2h`` (the
     reads: wait for the device and copy back); device time itself is in
     a device trace, not here.
@@ -318,7 +318,7 @@ def bench_profile(n_requests: int, B: int = 8,
     out: Dict = {"family": "paper", "B": B,
                  "n_requests_per_seed": n_requests, "engines": {}}
     for engine in engines:
-        sim = Simulator(sc, engine="numpy" if engine == "pallas" else engine)
+        sim = Simulator(sc, engine=engine)
         try:
             results = sim.run_batch(
                 workloads,

@@ -56,6 +56,7 @@ def main() -> None:
               f"({len(rule_names())} rules)", flush=True)
     if args.only in (None, "engine"):
         from benchmarks import engine_bench
+        from repro.sim.event_core import DEVICE_ENGINES
         record = engine_bench.main(smoke=args.smoke)
         if args.smoke:
             # CI guard: the profile section must carry a real per-phase
@@ -67,7 +68,7 @@ def main() -> None:
                 raise RuntimeError(
                     "BENCH_pr7.json profile section lacks per-phase "
                     f"tables (ran={sorted(ran)}, empty={bad})")
-            dev = [e for e in ran if e in ("jax", "pallas")]
+            dev = [e for e in ran if e in DEVICE_ENGINES]
             missing = [e for e in dev
                        if not {"core.h2d", "core.d2h"}
                        <= set(ran[e]["phases"])]

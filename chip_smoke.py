@@ -19,14 +19,6 @@ Phases, each printing its own ``#`` lines:
    on every discrete outcome: the summary fields (per-class counts and
    violations, fulfilment, migrations), ``n_events``, ``n_requests`` and
    ``infeasible_events``.
-3. kernels — ``event_step`` at [32, 1536] on seeded float32 state against
-   ``event_step_jax`` on the same inputs, and ``alloc_active_set`` at
-   [480, 128] and [8, 1536] against ``solve_resource_np``, both compiled
-   for the chip (``interpret=False``).  Tolerances: ``sid`` and
-   ``started`` equal; ``t_comp`` within ``rtol=1e-6``; the residuals within
-   ``atol=1e-5`` times the largest input residual (float32, a few
-   roundings per element); the allocator within ``rtol=1e-4``,
-   ``atol=capacity*1e-5`` with equal feasibility.
 
 The last line of standard output is one JSON object,
 ``{"ok": ..., "device": {"platform", "kind", "count"}}``.  Reports go to
@@ -49,9 +41,6 @@ sys.path.insert(0, str(ROOT / "src"))
 # size of phase 2: (n_nodes, seeds, batch, AI requests per seed)
 FULL = (480, 32, 32, 1000)
 TINY = (12, 4, 4, 60)
-# phase 3 shapes: event_step [B, S]; alloc_active_set [N, S] each
-KERNELS_FULL = ((32, 1536), ((480, 128), (8, 1536)))
-KERNELS_TINY = ((10, 200), ((5, 12), (9, 130)))
 
 TIMING_KEYS = ("wall_s", "engine_wall_s", "events_per_sec", "engine")
 HAF = {"name": "haf", "label": "haf",
@@ -226,117 +215,6 @@ def phase_sweep(args, dev, out: pathlib.Path):
             "numpy_wall_s": ref["wall_s"], "rows": n}
 
 
-def step_state(B: int, S: int, seed: int):
-    """Seeded float32 head state for ``event_step``.
-
-    Every instance is GPU-only (rem_c = 0), CPU-only (rem_g = 0), or in
-    both stages with a GPU stage too long to finish within the step.  So
-    no instance crosses the GPU->CPU boundary during the step, where a
-    residual within float32 rounding of zero would decide whether its CPU
-    stage starts.  Some lanes are unavailable, some GPU stages stalled
-    (alloc 0), some rows dead, and half the rows stop at a heap event
-    before their next completion."""
-    import numpy as np
-    rng = np.random.default_rng(seed)
-    kind = rng.integers(0, 3, (B, S))
-    ag = rng.uniform(0.5, 2.0, (B, S))
-    ac = rng.uniform(0.5, 2.0, (B, S))
-    rg = np.where(kind == 1, 0.0, rng.uniform(0.1, 10.0, (B, S)))
-    rg = np.where(kind == 2, rg + 1e3 * ag, rg)      # both: GPU stage long
-    rc = np.where(kind == 0, 0.0, rng.uniform(0.1, 10.0, (B, S)))
-    ag = np.where(rng.random((B, S)) < 0.05, 0.0, ag)
-    avail = rng.random((B, S)) < 0.9
-    t = rng.uniform(0.0, 100.0, B)
-    t_ev = np.where(np.arange(B) % 2 == 0, np.inf,
-                    t + rng.uniform(0.0, 0.05, B))
-    live = rng.random(B) < 0.9
-    f32 = np.float32
-    return (rg.astype(f32), rc.astype(f32), ag.astype(f32), ac.astype(f32),
-            avail, t.astype(f32), t_ev.astype(f32), live)
-
-
-def check_event_step(B: int, S: int, interpret: bool) -> dict:
-    import jax
-    import numpy as np
-    from repro.kernels.event_core import event_step_jax
-    from repro.kernels.event_step import event_step
-    args = step_state(B, S, seed=0)
-    got = jax.block_until_ready(event_step(*args, interpret=interpret))
-    want = jax.block_until_ready(event_step_jax(*args))
-    got = [np.asarray(x) for x in got]
-    want = [np.asarray(x) for x in want]
-    if got[0].dtype != np.float32:
-        raise RuntimeError(f"event_step ran in {got[0].dtype}, not float32")
-    scale = float(max(np.abs(args[0]).max(), np.abs(args[1]).max()))
-    if not np.array_equal(got[4], want[4]):
-        raise RuntimeError(f"event_step sid differs in rows "
-                           f"{np.nonzero(got[4] != want[4])[0].tolist()}")
-    if not np.array_equal(got[2], want[2]):
-        raise RuntimeError(f"event_step started differs at "
-                           f"{int((got[2] != want[2]).sum())} elements")
-    np.testing.assert_allclose(got[3], want[3], rtol=1e-6)
-    np.testing.assert_allclose(got[0], want[0], rtol=0, atol=1e-5 * scale)
-    np.testing.assert_allclose(got[1], want[1], rtol=0, atol=1e-5 * scale)
-    err = max(float(np.abs(got[i] - want[i]).max()) for i in (0, 1))
-    finite = np.isfinite(want[3])
-    terr = float(np.abs(got[3][finite] - want[3][finite]).max()) \
-        if finite.any() else 0.0
-    return {"shape": [B, S], "max_abs_err_rem": err, "rem_scale": scale,
-            "max_abs_err_t_comp": terr}
-
-
-def check_alloc(N: int, S: int, seed: int) -> dict:
-    import jax.numpy as jnp
-    import numpy as np
-    from repro.core.allocator_np import solve_resource_np
-    from repro.kernels import ops
-    rng = np.random.default_rng(seed)
-    psi = rng.uniform(0, 1e14, (N, S))
-    omega = rng.uniform(0, 100, (N, S))
-    cap = rng.uniform(5e13, 2e14, N)
-    # floors sum to about the capacity: feasible and infeasible rows mix
-    floors = np.where(rng.random((N, S)) < 0.3,
-                      rng.uniform(0, 1, (N, S)) * (cap / (0.15 * S))[:, None],
-                      0.0)
-    mask = rng.random((N, S)) < 0.9
-    al, fe, _ = ops.alloc_active_set(
-        jnp.asarray(psi), jnp.asarray(omega), jnp.asarray(floors),
-        jnp.asarray(cap), jnp.asarray(mask))
-    al, fe = np.asarray(al), np.asarray(fe)
-    worst = 0.0
-    for n in range(N):
-        a_np, f_np, _ = solve_resource_np(psi[n], omega[n], floors[n],
-                                          float(cap[n]), mask[n])
-        np.testing.assert_allclose(al[n], a_np, rtol=1e-4,
-                                   atol=cap[n] * 1e-5,
-                                   err_msg=f"alloc_active_set row {n}")
-        if bool(fe[n]) != bool(f_np):
-            raise RuntimeError(f"alloc_active_set feasibility differs in "
-                               f"row {n}")
-        worst = max(worst, float(np.abs(al[n] - a_np).max() / cap[n]))
-    return {"shape": [N, S], "feasible_rows": int(fe.sum()),
-            "max_abs_err_over_cap": worst}
-
-
-def phase_kernels(args, dev) -> dict:
-    from repro.kernels import ops
-    (B, S), alloc_shapes = KERNELS_TINY if args.tiny else KERNELS_FULL
-    interpret = dev["platform"] != "tpu"
-    if interpret and not args.tiny:
-        raise RuntimeError("kernels would run in interpret mode")
-    if ops._interpret() != interpret:
-        raise RuntimeError("ops.alloc_active_set would interpret on the chip")
-    say(f"# phase 3 kernels on {dev['kind']}: interpret={interpret}")
-    out = {"interpret": interpret,
-           "event_step": check_event_step(B, S, interpret)}
-    say(f"# phase 3 event_step {out['event_step']}")
-    out["alloc_active_set"] = [check_alloc(N, S, seed=i)
-                               for i, (N, S) in enumerate(alloc_shapes)]
-    for r in out["alloc_active_set"]:
-        say(f"# phase 3 alloc_active_set {r}")
-    return out
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--tiny", action="store_true",
@@ -358,7 +236,6 @@ def main(argv=None) -> int:
         dev = phase_device(args)
         summary["device"] = dev
         summary["sweep"] = phase_sweep(args, dev, out)
-        summary["kernels"] = phase_kernels(args, dev)
         summary["ok"] = dev["platform"] == "tpu" and not args.tiny
     except Exception as err:                # noqa: BLE001 — reported below
         traceback.print_exc()

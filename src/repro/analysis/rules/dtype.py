@@ -6,8 +6,8 @@ operations — a single f32 cast or an implicit-dtype array construction
 in those modules desyncs the event schedule within a handful of
 events.  This rule bans bare ``np.float32``/``jnp.float32`` and
 implicit-dtype ``np.zeros``-family constructions in the f64-path
-modules; deliberately-f32 TPU kernels (e.g. the VMEM-resident
-allocator) opt out with ``# repro: allow-file(float-dtype): <why>``.
+modules; a deliberately-f32 module would opt out with
+``# repro: allow-file(float-dtype): <why>``.
 """
 from __future__ import annotations
 
@@ -22,9 +22,6 @@ from repro.analysis.core import Finding, ModuleInfo, Rule, register
 FLOAT_DTYPE_SCOPE: Set[str] = {
     "sim/event_core.py",            # the numpy core of the contract
     "kernels/event_core.py",        # jax f64 twin
-    "kernels/event_step.py",        # Pallas [B, S] step kernel
-    "kernels/alloc_active_set.py",  # allocator kernel (f32 by design —
-                                    # carries an allow-file pragma)
 }
 
 _NP_NAMES = {"np", "numpy", "jnp"}

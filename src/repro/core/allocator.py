@@ -14,16 +14,15 @@ monotonically and the iteration converges in ≤ S steps.
 
 Everything here is pure JAX (jit/vmap-friendly, fixed shapes, no Python
 branching on values) so the same function:
-  * runs inside the event-driven simulator (single node or full cluster),
-  * is vmapped over nodes for the fleet-wide solve,
-  * serves as the reference oracle for the ``alloc_active_set`` Pallas
-    kernel (``repro.kernels.ref.alloc_active_set_ref`` wraps it).
+  * runs for one node or is vmapped over nodes for the fleet-wide solve
+    (:func:`allocate_cluster`),
+  * is the reference the simulator's NumPy twin
+    (:mod:`repro.core.allocator_np`) is tested against.
 
 Shapes: S = number of instances (padded, fixed); masks select residents.
 """
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -102,26 +101,13 @@ def allocate_node(psi_g: jax.Array, psi_c: jax.Array, omega: jax.Array,
     return g, c
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel",))
+@jax.jit
 def allocate_cluster(psi_g: jax.Array, psi_c: jax.Array, omega: jax.Array,
                      floors_g: jax.Array, floors_c: jax.Array,
                      gpu_capacity: jax.Array, cpu_capacity: jax.Array,
-                     mask: jax.Array, use_kernel: bool = False
-                     ) -> Tuple[AllocResult, AllocResult]:
+                     mask: jax.Array) -> Tuple[AllocResult, AllocResult]:
     """Fleet-wide allocation: everything is [N, S]; capacities are [N].
-
-    ``use_kernel=True`` routes the solve through the Pallas
-    ``alloc_active_set`` kernel (one grid step per node, VMEM-resident
-    instance vectors) — the TPU-native scale-out of the paper's per-node
-    millisecond loop.
-    """
-    if use_kernel:
-        from repro.kernels import ops as kops
-        ag, fg, pg = kops.alloc_active_set(psi_g, omega, floors_g,
-                                           gpu_capacity, mask)
-        ac, fc, pc = kops.alloc_active_set(psi_c, omega, floors_c,
-                                           cpu_capacity, mask)
-        return (AllocResult(ag, fg, pg), AllocResult(ac, fc, pc))
+    :func:`solve_resource` vmapped over the nodes, once per resource."""
     solve = jax.vmap(solve_resource, in_axes=(0, 0, 0, 0, 0))
     g = solve(psi_g, omega, floors_g, gpu_capacity, mask)
     c = solve(psi_c, omega, floors_c, cpu_capacity, mask)

@@ -4,7 +4,7 @@ The discrete-event simulator re-allocates on every arrival/completion/epoch/
 migration event (tens of thousands of times per run); going through JAX
 dispatch each time would dominate the runtime.  This module implements the
 *identical* active-set closed form (Eq. 17–19) in NumPy.  Equality with the
-JAX version (and with the Pallas kernel) is asserted by property tests.
+JAX version (:mod:`repro.core.allocator`) is asserted by tests.
 """
 from __future__ import annotations
 

@@ -33,7 +33,7 @@ from repro.exp import (ArtifactError, ExperimentSpec, GrammarError,
 from repro.exp.provenance import completed_rows, load_prior_report
 from repro.exp.runner import expand_experiment
 from repro.jax_cache import enable_compile_cache
-from repro.sim.event_core import DEVICE_ENGINES
+from repro.sim.event_core import DEVICE_ENGINES, ENGINES
 
 DEFAULT_METHODS = "haf,haf-static,round-robin,lyapunov"
 DEFAULT_SCENARIOS = "paper,diurnal,flash-crowd"
@@ -73,15 +73,15 @@ def _build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--rho", type=float, default=None,
                     help="override the load point for every scenario")
     ap.add_argument("--workers", type=int, default=None,
-                    help="sweep processes [default: up to 4; 1 for the "
-                         "device engines jax and pallas]")
+                    help="sweep processes [default: up to 4; 1 for a "
+                         f"device engine ({', '.join(DEVICE_ENGINES)})]")
     ap.add_argument("--batch", type=int, default=None, metavar="B",
                     help="fan up to B seeds of each (scenario, method) cell "
                          "into one batched [B, S] simulation")
     ap.add_argument("--engine", default=None,
-                    choices=("numpy", "scalar", "jax", "pallas"),
-                    help="event core backend (scalar = debug reference; "
-                         "pallas = batched kernel, needs --batch > 1)")
+                    choices=ENGINES,
+                    help="event core backend (numpy = default; scalar = "
+                         "debug reference; jax = the device core)")
     ap.add_argument("--epoch-interval", type=float, default=None)
     ap.add_argument("--max-events", type=int, default=None,
                     help="per-run event budget; hitting it marks the run "

@@ -54,7 +54,7 @@ class SweepSpec:
     max_events: int = 5_000_000
     workers: int = 1
     scenario_seed: int = 0                  # topology seed (workload varies)
-    engine: str = "numpy"                   # numpy | scalar | jax | pallas
+    engine: str = "numpy"                   # a name in sim.event_core.ENGINES
     batch_seeds: int = 1                    # >1: fan seeds into run_batch
     # streaming arrivals: feed the engine the chunked ArrivalStream and
     # drop the per-request result list (O(S + window) memory instead of
@@ -222,9 +222,6 @@ def run_job(job: Dict) -> Dict:
     from repro.sim import Simulator
 
     engine = job.get("engine", "numpy")
-    if engine == "pallas":
-        raise ValueError("engine='pallas' is batch-only; "
-                         "set batch_seeds > 1 (CLI: --batch)")
     sc = job.get("scenario") or scenario_for_job(job)
     stream, info, streamed = _job_stream(job, sc)
     placement, allocation, rr = make_method(job["method"],

@@ -42,9 +42,6 @@ from repro.exp.grammar import GrammarError
 
 __all__ = ["ExperimentSpec", "SpecError", "load_experiment"]
 
-ENGINES = ("numpy", "scalar", "jax", "pallas")
-
-
 class SpecError(ValueError):
     """An experiment spec that cannot run; the message lists every problem."""
 
@@ -337,6 +334,7 @@ class ExperimentSpec:
         """Raise :class:`SpecError` listing every problem (else return)."""
         from repro.eval.policies import _REGISTRY, method_names
         from repro.eval.sweep import device_workers_error
+        from repro.sim.event_core import ENGINES
         from repro.sim.scenarios import family_names
         from repro.sim.scenarios.registry import family_params
 
@@ -397,9 +395,6 @@ class ExperimentSpec:
         workers_err = device_workers_error(self.engine, self.workers)
         if workers_err:
             problems.append(workers_err)
-        if self.engine == "pallas" and self.batch <= 1:
-            problems.append("engine='pallas' is the batched kernel backend; "
-                            "set batch > 1")
         if self.epoch_interval <= 0:
             problems.append("epoch_interval must be > 0")
         if self.metrics_interval < 0:

@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache for the entry points that can reach
 the device (``python -m repro.eval``, ``benchmarks/run.py``,
-``chip_smoke.py``).
+``bench/run.py``, ``bench/spans.py`` and ``chip_smoke.py``'s sweep).
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
 module sets nothing.  Otherwise the cache goes to one fixed directory

@@ -13,12 +13,9 @@ default dtypes.  On CPU the per-event dispatch makes
 this slower than numpy; the backend exists as the accelerator-resident
 growth path.  :func:`event_step_jax` is the batched form: the [S]
 vectors become [B, S] blocks (B seeds of one scenario×method cell in
-lockstep, one fused device call per tick), and the same expressions are
-a Pallas TPU kernel in :mod:`repro.kernels.event_step` alongside
-:mod:`repro.kernels.alloc_active_set` (lane reductions over the padded
-instance dimension).  :func:`event_step_jax_packed` runs the batched
-step on one packed buffer each way, so a tick is one transfer in and one
-out.
+lockstep, one fused device call per tick).  :func:`event_step_jax_packed`
+runs the batched step on one packed buffer each way, so a tick is one
+transfer in and one out.
 
 Like every module in this package, importing it requires jax; the
 simulator only imports it when ``engine="jax"`` is selected.
@@ -92,9 +89,8 @@ def event_step_jax(rem_g: jax.Array, rem_c: jax.Array,
     Returns ``(rem_g', rem_c', started, t_comp [B], sid [B])`` — the
     step of each lockstep tick of ``Simulator.run_batch``, whose operands
     and results cross as one packed buffer each way
-    (:func:`event_step_jax_packed`).  This is the jnp form of the Pallas
-    kernel in :mod:`repro.kernels.event_step`; both evaluate the
-    expressions of the numpy batched core elementwise.
+    (:func:`event_step_jax_packed`).  It evaluates the expressions of the
+    numpy batched core elementwise.
 
     The ``maximum(·, 0)`` around each work product is exact (the product
     is never negative where it is taken).  It keeps XLA:CPU from

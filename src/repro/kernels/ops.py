@@ -11,9 +11,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import LANES, ROWS
-from repro.kernels import ref as kref
-from repro.kernels.alloc_active_set import alloc_active_set_ns
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.rmsnorm import rmsnorm_2d
 from repro.kernels.ssd_scan import ssd_scan_bhsp
@@ -21,15 +18,6 @@ from repro.kernels.ssd_scan import ssd_scan_bhsp
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def _pad_to(x: jax.Array, size: int, axis: int) -> jax.Array:
-    pad = size - x.shape[axis]
-    if pad <= 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
 
 
 # --------------------------------------------------------------------------- #
@@ -90,33 +78,6 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     y = y.reshape(b, h, s, p).transpose(0, 2, 1, 3)
     state = state.reshape(b, h, p, n).astype(x.dtype)
     return y, state
-
-
-# --------------------------------------------------------------------------- #
-# deadline-aware active-set allocation (the paper's Eq. 17–19)
-# --------------------------------------------------------------------------- #
-def alloc_active_set(psi: jax.Array, omega: jax.Array, floors: jax.Array,
-                     capacity: jax.Array, mask: jax.Array
-                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """[N, S] fleet allocation. Returns (alloc [N,S], feasible [N], pinned).
-
-    Pads nodes to the kernel's row block and instances to a lane multiple;
-    padded rows and lanes are masked out and stripped from the result.
-    """
-    N, S = psi.shape
-    N_pad = -(-N // ROWS) * ROWS
-    S_pad = -(-S // LANES) * LANES
-
-    def block(x, dtype):
-        return _pad_to(_pad_to(x.astype(dtype), S_pad, 1), N_pad, 0)
-
-    cap = _pad_to(capacity.astype(jnp.float32).reshape(N, 1), N_pad, 0)
-    alloc, feas, pinned = alloc_active_set_ns(
-        block(psi, jnp.float32), block(omega, jnp.float32),
-        block(floors, jnp.float32), cap, block(mask, jnp.int32),
-        interpret=_interpret())
-    return (alloc[:N, :S], feas[:N, 0].astype(bool),
-            pinned[:N, :S].astype(bool))
 
 
 # --------------------------------------------------------------------------- #

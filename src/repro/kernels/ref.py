@@ -74,18 +74,6 @@ def ssd_scan_sequential_ref(x: jax.Array, dt: jax.Array, A: jax.Array,
 
 
 # --------------------------------------------------------------------------- #
-# deadline-aware active-set allocation (the paper's Eq. 17–19)
-# --------------------------------------------------------------------------- #
-def alloc_active_set_ref(psi: jax.Array, omega: jax.Array, floors: jax.Array,
-                         capacity: jax.Array, mask: jax.Array
-                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """[N,S] batched closed form — vmapped repro.core.allocator oracle."""
-    from repro.core.allocator import solve_resource
-    res = jax.vmap(solve_resource)(psi, omega, floors, capacity, mask)
-    return res.alloc, res.feasible, res.floored
-
-
-# --------------------------------------------------------------------------- #
 # fused RMS norm
 # --------------------------------------------------------------------------- #
 def rmsnorm_ref(x: jax.Array, weight: jax.Array,

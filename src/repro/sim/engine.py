@@ -937,12 +937,7 @@ class Simulator:
         # RunObserver, or None (off — the hot path is then bit-identical
         # to the uninstrumented engine).  run()/run_batch() can override.
         self.obs = obs
-        # fail fast on unknown names; "pallas" is batch-only, so it
-        # validates against the batched registry and run() rejects it
-        if engine == "pallas":
-            make_batched_event_core(engine)
-        else:
-            make_event_core(engine)
+        make_event_core(engine)         # fail fast on unknown names
 
     # ------------------------------------------------------------------ #
     def run(self, requests,
@@ -957,10 +952,6 @@ class Simulator:
         ``retain_requests=False`` drops the per-request list from the
         result (summaries come from the streaming accumulators) — with a
         windowed stream the whole run is then O(S + window) memory."""
-        if self.engine == "pallas":
-            raise ValueError(
-                "engine='pallas' is the batched [B, S] kernel backend; "
-                "use run_batch, or engine='numpy' for single traces")
         observer = _obs.make_observer(obs if obs is not None else self.obs,
                                       B=1, engine=self.engine)
         prof = metrics = None
@@ -1095,8 +1086,8 @@ class Simulator:
         compatible agentic policies batch their candidate features and
         critic forward instead of paying B Python callbacks.  Every
         replica's discrete outcome is identical to a solo ``run`` with
-        the same seed.  ``engine`` overrides the batched core
-        (``numpy | scalar | jax | pallas``); the default reuses the
+        the same seed.  ``engine`` overrides the batched core (a name in
+        :data:`~repro.sim.event_core.ENGINES`); the default reuses the
         simulator's engine name.
         """
         B = len(workloads)

@@ -32,10 +32,9 @@ cores below (``make_batched_event_core``): B replicas' arrays stack into
 one ``[B, S]`` :class:`~repro.sim.cluster.ClusterBlock` and the whole
 block advances per lockstep tick — ``numpy`` (elementwise-identical to
 the solo pair, so batched outcomes are bit-for-bit the solo outcomes),
-``scalar`` (per-row reference), ``jax`` (one fused jitted device call
-per tick), and ``pallas`` (the fused step as a Pallas kernel,
-:mod:`repro.kernels.event_step`, in float64 interpret mode; refused on
-a TPU, where Mosaic has no float64).
+``scalar`` (per-row reference) and ``jax`` (one fused jitted device call
+per tick, one packed buffer each way).  Every engine name in
+:data:`ENGINES` has a solo and a batched core.
 """
 from __future__ import annotations
 
@@ -49,14 +48,15 @@ INF = float("inf")
 
 # Every core exposes ``profiler`` (a repro.obs Profiler or None, attached
 # by the Simulator per run).  The numpy/scalar cores' work is already
-# timed by the simulator's "engine.step" span; the jax/pallas cores split it
-# into two spans around the calls the unprofiled path makes anyway:
-# ``core.h2d`` — packing the operands (batched cores) and the call into
-# the jitted program, which copies them in and enqueues the step — and
-# ``core.d2h`` — the reads of its outputs, which wait for the device and
-# copy back, and their unpacking.  They count ``core.ticks`` (steps
-# dispatched), ``core.h2d_transfers`` / ``core.d2h_transfers`` (arrays
-# passed and read back: one each a batched tick) and ``core.h2d_bytes``
+# timed by the simulator's "engine.step" span; the two jax cores (solo
+# and batched) split it into two spans around the calls the unprofiled
+# path makes anyway: ``core.h2d`` — packing the operands (batched core)
+# and the call into the jitted program, which copies them in and
+# enqueues the step — and ``core.d2h`` — the reads of its outputs, which
+# wait for the device and copy back, and their unpacking.  They count
+# ``core.ticks`` (steps dispatched), ``core.h2d_transfers`` /
+# ``core.d2h_transfers`` (arrays passed and read back: one each a
+# batched tick, 6 in and 2 or 3 out a solo call) and ``core.h2d_bytes``
 # / ``core.d2h_bytes`` (their bytes).  Profiling adds
 # no staging and no synchronisation: device time is the device trace's.
 
@@ -318,7 +318,12 @@ class JaxEventCore:
                                rg.nbytes + rc.nbytes + started.nbytes)
 
 
+# the engine names: each has a solo core (make_event_core) and a batched
+# one (make_batched_event_core)
 ENGINES = ("numpy", "scalar", "jax")
+# engines whose step runs on the JAX default device (the chip, on a TPU
+# host): a sweep runs their jobs in the one process that holds it
+DEVICE_ENGINES = ("jax",)
 
 
 def make_event_core(engine: str):
@@ -474,9 +479,12 @@ class JaxBatchedEventCore:
     A tick crosses to the device and back once each way: the eight
     operands are packed into one preallocated float64 host buffer, and
     :func:`~repro.kernels.event_core.event_step_jax_packed` slices them
-    apart on the device, runs :meth:`_step_fn` on them and returns the
-    five results as one array, which is unpacked into the block (the
-    layout is :mod:`repro.kernels.event_core`'s).
+    apart on the device, runs
+    :func:`~repro.kernels.event_core.event_step_jax` on them and returns
+    the five results as one array, which is unpacked into the block (the
+    layout is :mod:`repro.kernels.event_core`'s).  The step is looked up
+    in its module on every tick, and the program's compile cache is keyed
+    on it.
     """
 
     name = "jax"
@@ -488,12 +496,6 @@ class JaxBatchedEventCore:
         self._jax = jax
         self._kernel = kec
         self._shape = None
-
-    def _step_fn(self):
-        """The eight-operand [B, S] step that the packed program runs;
-        looked up each tick, and the program's compile cache is keyed on
-        it."""
-        return self._kernel.event_step_jax
 
     def _ensure_scratch(self, B: int, S: int) -> None:
         if self._shape != (B, S):
@@ -515,7 +517,7 @@ class JaxBatchedEventCore:
             buf = kec.pack_step_inputs(
                 self._in, block.head_rem_g, block.head_rem_c,
                 block.alloc_g, block.alloc_c, avail, t_vec, t_ev, can)
-            out = kec.event_step_jax_packed(buf, step=self._step_fn())
+            out = kec.event_step_jax_packed(buf, step=kec.event_step_jax)
             if prof is not None:
                 prof.end()
                 prof.add_count("core.ticks", 1)
@@ -535,54 +537,18 @@ class JaxBatchedEventCore:
             return ret
 
 
-class PallasBatchedEventCore(JaxBatchedEventCore):
-    """The [B, S] step as a Pallas kernel (8-replica row blocks).
-
-    The block state is float64, which Mosaic cannot compile, so this
-    core runs the kernel in interpret mode, which keeps float64 and
-    therefore the same discrete-outcome bar as the jax core.  On a TPU it
-    refuses to construct rather than cast the state or interpret the
-    kernel on the chip.  See :mod:`repro.kernels.event_step`.  The
-    packing each way is the jax core's.
-    """
-
-    name = "pallas"
-
-    def __init__(self) -> None:
-        import jax
-        if jax.default_backend() == "tpu":
-            raise RuntimeError(
-                "engine='pallas' cannot run on a TPU: its [B, S] block state "
-                "is float64 and Mosaic has no 64-bit element type; use "
-                "engine='jax' (XLA emulates float64 on the chip)")
-        super().__init__()
-        from repro.kernels import event_step as kes
-        self._step_kernel = kes
-
-    def _step_fn(self):
-        # interpret mode is event_step's default
-        return self._step_kernel.event_step
-
-
-BATCH_ENGINES = ("numpy", "scalar", "jax", "pallas")
-# engines whose step runs on the JAX default device (the chip, on a TPU
-# host): a sweep runs their jobs in the one process that holds it
-DEVICE_ENGINES = ("jax", "pallas")
-
-
 def make_batched_event_core(engine: str):
     """``engine`` -> batched event core (raises on unknown names)."""
     if engine == "numpy":
         return NumpyBatchedEventCore()
     if engine == "scalar":
         return ScalarBatchedEventCore()
-    if engine in ("jax", "pallas"):
+    if engine == "jax":
         try:
-            return JaxBatchedEventCore() if engine == "jax" \
-                else PallasBatchedEventCore()
+            return JaxBatchedEventCore()
         except ImportError as err:
             raise RuntimeError(
-                f"engine={engine!r} needs jax installed; "
-                "use engine='numpy'") from err
+                "engine='jax' needs jax installed; use engine='numpy'"
+            ) from err
     raise ValueError(
-        f"unknown batched engine {engine!r}; known: {BATCH_ENGINES}")
+        f"unknown batched engine {engine!r}; known: {ENGINES}")

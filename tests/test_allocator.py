@@ -4,7 +4,8 @@ The paper's claim is that the active-set closed form IS the argmin of the
 convex problem (16).  We certify:
   * KKT optimality vs a numeric projected-gradient solve,
   * the capacity and floor constraints as invariants under random inputs,
-  * exact agreement between the JAX, NumPy, and Pallas implementations.
+  * agreement between the JAX and NumPy implementations, per node and
+    fleet-wide (``allocate_cluster``).
 """
 import jax
 import jax.numpy as jnp
@@ -29,7 +30,6 @@ except ImportError:
 
 from repro.core import allocator
 from repro.core.allocator_np import active_set_np, solve_resource_np
-from repro.kernels import ops as kops
 
 S = 12
 
@@ -99,9 +99,33 @@ def test_jax_equals_numpy(seed, feas):
     assert bool(res.feasible) == bool(f_np)
 
 
+def _cluster_vs_oracle(psi, omega, floors, cap, mask):
+    """Solve both resources of an [N, S] fleet with ``allocate_cluster``
+    and check each node against ``solve_resource_np``; returns the GPU
+    feasibility per node.  The CPU problem is the GPU one scaled to core
+    counts, with its own floors."""
+    N = psi.shape[0]
+    psi_c, cap_c = psi * 1e-12, cap * 1e-12
+    floors_c = floors[:, ::-1] * 1e-12
+    g, c = allocator.allocate_cluster(
+        jnp.asarray(psi), jnp.asarray(psi_c), jnp.asarray(omega),
+        jnp.asarray(floors), jnp.asarray(floors_c), jnp.asarray(cap),
+        jnp.asarray(cap_c), jnp.asarray(mask))
+    for res, p, f, k in ((g, psi, floors, cap), (c, psi_c, floors_c, cap_c)):
+        assert res.alloc.shape == (N, psi.shape[1])
+        assert res.feasible.shape == (N,)
+        for n in range(N):
+            a_np, f_np, _ = solve_resource_np(p[n], omega[n], f[n],
+                                              float(k[n]), mask[n])
+            np.testing.assert_allclose(np.asarray(res.alloc[n]), a_np,
+                                       rtol=1e-4, atol=k[n] * 1e-5)
+            assert bool(res.feasible[n]) == bool(f_np)
+    return [bool(x) for x in np.asarray(g.feasible)]
+
+
 @settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 10_000))
-def test_pallas_kernel_equals_oracle(seed):
+def test_allocate_cluster_equals_oracle(seed):
     rng = np.random.default_rng(seed)
     N = 4
     psi = rng.uniform(0, 1e14, (N, S))
@@ -110,22 +134,13 @@ def test_pallas_kernel_equals_oracle(seed):
     floors = np.where(rng.random((N, S)) < 0.3,
                       rng.uniform(0, 2e13, (N, S)), 0.0)
     mask = rng.random((N, S)) < 0.9
-    al, fe, pin = kops.alloc_active_set(
-        jnp.asarray(psi), jnp.asarray(omega), jnp.asarray(floors),
-        jnp.asarray(cap), jnp.asarray(mask))
-    for n in range(N):
-        a_np, f_np, _ = solve_resource_np(psi[n], omega[n], floors[n],
-                                          float(cap[n]), mask[n])
-        np.testing.assert_allclose(np.asarray(al[n]), a_np, rtol=1e-4,
-                                   atol=cap[n] * 1e-5)
-        assert bool(fe[n]) == bool(f_np)
+    _cluster_vs_oracle(psi, omega, floors, cap, mask)
 
 
 @pytest.mark.parametrize("N,S_,seed", ((5, 12, 0), (13, 200, 1)))
-def test_pallas_kernel_padded_rows_equal_oracle(N, S_, seed):
-    """The kernel works on 8-node row blocks and 128-lane instance rows:
-    node and instance counts off those multiples are padded with masked
-    rows and lanes, which must not leak into the real rows."""
+def test_allocate_cluster_rows_equal_oracle(N, S_, seed):
+    """The fleet-wide solve is the per-node solve on every row, for both
+    resources, with feasible and infeasible nodes in one fleet."""
     rng = np.random.default_rng(seed)
     psi = rng.uniform(0, 1e14, (N, S_))
     omega = rng.uniform(0, 100, (N, S_))
@@ -135,18 +150,7 @@ def test_pallas_kernel_padded_rows_equal_oracle(N, S_, seed):
     floors = np.where(rng.random((N, S_)) < 0.3,
                       rng.uniform(0, 1, (N, S_)) * cap_share, 0.0)
     mask = rng.random((N, S_)) < 0.9
-    al, fe, pin = kops.alloc_active_set(
-        jnp.asarray(psi), jnp.asarray(omega), jnp.asarray(floors),
-        jnp.asarray(cap), jnp.asarray(mask))
-    assert al.shape == (N, S_) and fe.shape == (N,) and pin.shape == (N, S_)
-    feas = []
-    for n in range(N):
-        a_np, f_np, _ = solve_resource_np(psi[n], omega[n], floors[n],
-                                          float(cap[n]), mask[n])
-        np.testing.assert_allclose(np.asarray(al[n]), a_np, rtol=1e-4,
-                                   atol=cap[n] * 1e-5)
-        assert bool(fe[n]) == bool(f_np)
-        feas.append(bool(f_np))
+    feas = _cluster_vs_oracle(psi, omega, floors, cap, mask)
     assert any(feas) and not all(feas)
 
 

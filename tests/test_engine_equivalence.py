@@ -3,7 +3,8 @@
 The vectorized numpy engine (default) must be bit-for-bit equivalent to
 the scalar reference across every scenario family and seed: identical
 ``SimResult.summary()``, migration sequences, and drop sets.  The jax
-backend is held to the same bar when jax is installed.
+backend is held to the same bar when jax is installed.  The engine names
+are one registry, ``sim.event_core.ENGINES``, at every entry point.
 
 Also pins the Eq. 1 stage-ordering fix: CPU work must not progress while
 the GPU stage is stalled (the historical ``advance``/``next_completion``
@@ -206,13 +207,14 @@ def test_run_batch_scalar_core_matches():
     assert batch == solos
 
 
-@pytest.mark.parametrize("engine", ("jax", "pallas"))
-def test_run_batch_jax_and_pallas_cores(engine):
-    """The device cores are held to the jax bar: identical discrete
-    outcomes, finish times to ~1 ulp (XLA may fuse multiply-adds)."""
+@pytest.mark.parametrize("family", ("paper", "dense-urban"))
+def test_run_batch_jax_core_holds_the_chip_bar(family):
+    """The bar the batched jax core is held to on any backend: identical
+    discrete outcomes to the solo numpy runs, finish times to ~1 ulp
+    (on a TPU, XLA emulates float64 and may fuse multiply-adds)."""
     pytest.importorskip("jax")
-    solos = [_run("numpy", "paper", s) for s in BATCH_SEEDS]
-    batch = _run_batch("paper", BATCH_SEEDS, engine=engine)
+    solos = [_run("numpy", family, s) for s in BATCH_SEEDS]
+    batch = _run_batch(family, BATCH_SEEDS, engine="jax")
     for solo, b in zip(solos, batch):
         assert _fingerprint(solo)[:4] == _fingerprint(b)[:4]
         fa = np.array([r.finish for r in solo.requests])
@@ -236,17 +238,47 @@ def test_run_batch_jax_core_is_the_numpy_core_on_cpu(family):
     assert got == want
 
 
-def test_pallas_core_refused_on_tpu(monkeypatch):
-    """Mosaic has no float64: on a TPU the pallas engine refuses at
-    construction and names the jax engine, instead of casting its state
-    or interpreting the kernel on the chip."""
-    jax = pytest.importorskip("jax")
+ENTRY_POINTS = ("simulator", "batched_core", "spec", "cli")
+
+
+def _accepts(entry: str, engine: str) -> bool:
+    """Whether ``entry`` takes ``engine`` as an engine name (False when
+    it refuses the name as unknown)."""
+    from repro.exp import ExperimentSpec, SpecError
     from repro.sim.event_core import make_batched_event_core
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(RuntimeError, match="float64.*engine='jax'"):
-        make_batched_event_core("pallas")
-    with pytest.raises(RuntimeError, match="float64"):
-        Simulator(paper_scenario(), engine="pallas")
+    try:
+        if entry == "simulator":
+            Simulator(paper_scenario(), engine=engine)
+        elif entry == "batched_core":
+            make_batched_event_core(engine)
+        elif entry == "spec":
+            ExperimentSpec(methods=("haf-static",), scenarios=("paper",),
+                           engine=engine, workers=1).validate()
+        else:
+            from repro.eval import cli
+            return engine in cli._build_parser()._option_string_actions[
+                "--engine"].choices
+    except (ValueError, SpecError) as err:
+        assert "unknown" in str(err) and "engine" in str(err)
+        return False
+    return True
+
+
+@pytest.mark.parametrize("entry", ENTRY_POINTS)
+def test_engine_registry_is_one(entry):
+    """Every entry point accepts the names of the one registry,
+    ``sim.event_core.ENGINES``, and refuses any other, ``pallas`` among
+    them, as an unknown engine; the CLI offers exactly the registry."""
+    pytest.importorskip("jax")
+    from repro.sim.event_core import DEVICE_ENGINES, ENGINES
+    assert set(DEVICE_ENGINES) <= set(ENGINES)
+    for engine in ENGINES:
+        assert _accepts(entry, engine), engine
+    assert not _accepts(entry, "pallas")
+    if entry == "cli":
+        from repro.eval import cli
+        action = cli._build_parser()._option_string_actions["--engine"]
+        assert tuple(action.choices) == ENGINES
 
 
 PACKED_CASES = {          # case -> B
@@ -286,22 +318,44 @@ def _bits(a):
     return np.asarray(a, np.float64).view(np.uint64)
 
 
-@pytest.mark.parametrize("engine", ("jax", "pallas"))
+def _numpy_core_step(state, t, t_ev, live):
+    """The numpy batched core's tick on a copy of ``state``: the block
+    it leaves, then ``t_comp`` and ``sid``."""
+    import types
+
+    from repro.sim.event_core import NumpyBatchedEventCore
+    B, S = state["head_rem_g"].shape
+    block = types.SimpleNamespace(B=B, S=S, **{k: np.copy(v) for k, v in
+                                               state.items()})
+    t_comp, sid = NumpyBatchedEventCore().step(block, t, t_ev, live)
+    return [block.head_rem_g, block.head_rem_c, block.head_started,
+            t_comp, sid]
+
+
+@pytest.mark.parametrize("reference", ("eight_operand", "numpy_core"))
 @pytest.mark.parametrize("case", sorted(PACKED_CASES))
-def test_packed_step_is_the_unpacked_step_bit_for_bit(engine, case):
-    """One packed buffer each way per tick: the block the device core
+def test_packed_step_is_the_unpacked_step_bit_for_bit(reference, case):
+    """One packed buffer each way per tick: the block the jax core
     leaves and the ``(t_comp, sid)`` it returns are, bit for bit, what
-    its eight-operand step gives on the same block."""
+    its eight-operand step gives on the same block (``eight_operand``),
+    and on XLA:CPU what the numpy batched core's tick gives
+    (``numpy_core``)."""
     import types
     jax = pytest.importorskip("jax")
+    from repro.kernels import event_core as kec
     from repro.sim.event_core import make_batched_event_core
     state, t, t_ev, live = _step_inputs(case, PACKED_CASES[case])
-    avail = state["head_mask"] & (state["reconfig_until"] <= t[:, None])
-    core = make_batched_event_core(engine)
-    with jax.enable_x64(True):
-        want = [np.asarray(o) for o in core._step_fn()(
-            state["head_rem_g"], state["head_rem_c"], state["alloc_g"],
-            state["alloc_c"], avail, t, t_ev, live)]
+    if reference == "eight_operand":
+        avail = state["head_mask"] & (state["reconfig_until"] <= t[:, None])
+        with jax.enable_x64(True):
+            want = [np.asarray(o) for o in kec.event_step_jax(
+                state["head_rem_g"], state["head_rem_c"], state["alloc_g"],
+                state["alloc_c"], avail, t, t_ev, live)]
+    else:
+        if jax.default_backend() != "cpu":
+            pytest.skip("the bit-for-bit bar holds for XLA:CPU")
+        want = _numpy_core_step(state, t, t_ev, live)
+    core = make_batched_event_core("jax")
     B, S = state["head_rem_g"].shape
     block = types.SimpleNamespace(B=B, S=S, **{k: np.copy(v) for k, v in
                                                state.items()})

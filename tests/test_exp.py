@@ -19,6 +19,7 @@ from repro.exp import (ArtifactError, ExperimentSpec, FingerprintMismatch,
                        parse_value, resolve_artifact, run_experiment,
                        save_critic)
 from repro.exp.provenance import completed_rows
+from repro.sim.event_core import DEVICE_ENGINES
 
 MOCK_LLM = pathlib.Path(__file__).resolve().parent / "mock_llm.py"
 
@@ -185,7 +186,7 @@ def test_validate_catches_everything():
         (dict(scenarios=("flash-crowd(magnitud=6)",)), "unknown parameter"),
         (dict(methods=("haf(agnt=x)",)), "unknown parameter"),
         (dict(methods=("haf-llm",)), "needs cmd="),
-        (dict(engine="pallas"), "batch > 1"),
+        (dict(engine="pallas"), "unknown engine"),
         (dict(seeds=()), "no seeds"),
         # duplicate labels would merge aggregation cells and cross-resume
         (dict(scenarios=("paper(rho=0.75)", "paper(rho=1.25)")),
@@ -414,7 +415,7 @@ def test_cli_legacy_haf_llm_comma_error(capsys):
 # --------------------------------------------------------------------------- #
 # the device engines: one process holds the chip, and nothing fails quietly
 # --------------------------------------------------------------------------- #
-@pytest.mark.parametrize("engine", ("jax", "pallas"))
+@pytest.mark.parametrize("engine", DEVICE_ENGINES)
 def test_device_engine_with_workers_fails_before_any_job(engine,
                                                          monkeypatch):
     ran = []
@@ -434,7 +435,7 @@ def test_device_engine_with_workers_fails_before_any_job(engine,
 
 def test_cli_defaults_to_one_worker_for_device_engines():
     ap = cli._build_parser()
-    for engine in ("jax", "pallas"):
+    for engine in DEVICE_ENGINES:
         built = cli.build_experiment(ap.parse_args(
             ["--engine", engine, "--batch", "4"]))
         assert built.workers == 1

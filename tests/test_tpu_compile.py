@@ -1,10 +1,10 @@
-"""Compile-only checks of the Pallas kernels for a described TPU v5e.
+"""Compile-only checks of the jax engine's batched tick for a described
+TPU v5e.
 
 The TPU compiler is installed without a chip: it compiles for a described
-topology and refuses what the chip would refuse (block shapes off the
-(8, 128) tiling, scalar stores to VMEM, 64-bit element types).  Nothing
-runs.  The topology is described inside a fixture, never at import,
-because only one process at a time may load the TPU library.
+topology and refuses what the chip would refuse.  Nothing runs.  The
+topology is described inside a fixture, never at import, because only
+one process at a time may load the TPU library.
 """
 import jax
 import jax.numpy as jnp
@@ -36,49 +36,14 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _tpu_kernel_count(compiled) -> int:
-    return compiled.as_text().count("tpu_custom_call")
-
-
-@pytest.mark.parametrize("B,S", ((32, 1536), (256, 4096)))
-def test_event_step_compiles_for_v5e(one_chip, B, S):
-    from repro.kernels.event_step import event_step
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    block, mask, col = sds((B, S), jnp.float32), sds((B, S), jnp.bool_), \
-        sds((B,), jnp.float32)
-    step = jax.jit(lambda *a: event_step(*a, interpret=False))
-    compiled = step.lower(block, block, block, block, mask, col, col,
-                          sds((B,), jnp.bool_)).compile()
-    assert _tpu_kernel_count(compiled) >= 1
-
-
-@pytest.mark.parametrize("N,S", ((480, 128), (8, 1536)))
-def test_alloc_active_set_compiles_for_v5e(one_chip, monkeypatch, N, S):
-    from repro.kernels import ops
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    # the wrapper picks interpret mode from the default backend (the CPU
-    # here); compile the kernel path the chip takes
-    monkeypatch.setattr(ops, "_interpret", lambda: False)
-    block = sds((N, S), jnp.float32)
-    compiled = jax.jit(ops.alloc_active_set).lower(
-        block, block, block, sds((N,), jnp.float32),
-        sds((N, S), jnp.bool_)).compile()
-    assert _tpu_kernel_count(compiled) >= 1
-
-
-
-@pytest.mark.parametrize("B", (32, 256))
-def test_packed_event_step_compiles_for_v5e(one_chip, B):
-    """The jax engine's tick at the benchmark's shapes (S = 18, float64):
+@pytest.mark.parametrize("B,S", ((32, 18), (256, 18), (32, 1440),
+                                 (256, 1440)))
+def test_packed_event_step_compiles_for_v5e(one_chip, B, S):
+    """The jax engine's tick (float64) at the benchmark's shapes (S = 18)
+    and the metro deployment's (``dense-urban(n_nodes=480)``, S = 1440):
     one packed buffer in, one packed array out, one program."""
     from repro.kernels import event_core as kec
-    w_in, w_out = kec.packed_widths(18)
+    w_in, w_out = kec.packed_widths(S)
     buf = jax.ShapeDtypeStruct((B, w_in), jnp.float64, sharding=one_chip)
     with jax.enable_x64(True):
         lowered = kec.event_step_jax_packed.lower(buf,
